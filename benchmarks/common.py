@@ -9,6 +9,7 @@ import time
 import jax
 import numpy as np
 
+from repro import runtime
 from repro.core import algorithms, expfam, gmm, network, refperm
 
 OUTDIR = os.path.join(os.path.dirname(os.path.dirname(
@@ -16,7 +17,7 @@ OUTDIR = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def setup_gmm(data, K, D, *, seed=0, graph_seed=0, beta0=0.1, w0=10.0):
-    expfam.enable_x64()
+    runtime.use_platform_precision()
     prior = expfam.noninformative_prior(K, D, beta0=beta0, w0_scale=w0)
     n = data.x.shape[0]
     adj, _ = network.random_geometric_graph(n, seed=graph_seed)

@@ -104,7 +104,10 @@ def vb_run(full=False):
 
 
 def run(full=False):
+    # the child emulates four host devices; it must never reach for an
+    # accelerator, which this process may already hold
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(here, "src")

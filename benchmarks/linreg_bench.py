@@ -13,11 +13,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
+from repro import runtime
 from repro.core import linreg, network
 
 
 def run(full=False):
-    jax.config.update("jax_enable_x64", True)
+    runtime.use_platform_precision()
     D, n_nodes, ni = 6, 50 if full else 20, 40
     rng = np.random.default_rng(0)
     w_true = rng.normal(size=D)

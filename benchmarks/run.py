@@ -40,6 +40,8 @@ def main() -> None:
                          "accumulates across PRs)")
     args, _ = ap.parse_known_args()
 
+    from repro import runtime
+    runtime.use_compile_cache()
     from benchmarks import consensus_bench, gmm_backend_bench, kernel_bench, \
         linreg_bench, minibatch_bench, paper_figures, roofline, \
         svrg_bench, topology_scale_bench, vb_service_bench, \

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.core import engine, expfam, gmm, network, refperm
 from repro.core import model as model_lib
 from repro.data import synthetic
@@ -69,7 +70,7 @@ def _no_dense_matrix_in_hlo(topo, n: int) -> bool:
 
 
 def run(full=False):
-    expfam.enable_x64()
+    runtime.use_platform_precision()
     rows, payload = [], {}
     for n in N_SWEEP:
         n_iters = _iters(n, full)

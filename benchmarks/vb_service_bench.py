@@ -31,6 +31,7 @@ import time
 import jax
 
 from benchmarks import common
+from repro import runtime
 
 
 def run(full: bool = False):
@@ -39,7 +40,7 @@ def run(full: bool = False):
     from repro.data import synthetic
     from repro.serving.vb_service import VBRequest, VBService
 
-    expfam.enable_x64()
+    runtime.use_platform_precision()
     K, D = 3, 2
     n_sessions = 16
     n_nodes = 16 if full else 8
@@ -104,7 +105,7 @@ def run_poisson(full: bool = False):
     from repro.data import synthetic
     from repro.serving.vb_service import VBRequest, VBService
 
-    expfam.enable_x64()
+    runtime.use_platform_precision()
     K, D = 3, 2
     n_sessions = 24 if full else 12
     n_nodes = 16 if full else 8
@@ -205,7 +206,7 @@ def run_mixed_fleet(full: bool = False):
     from repro.data import synthetic
     from repro.serving.vb_service import VBRequest, VBService
 
-    expfam.enable_x64()
+    runtime.use_platform_precision()
     K, D = 3, 2
     n_sessions = 64
     n_nodes = 16 if full else 8

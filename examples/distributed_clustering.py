@@ -12,6 +12,7 @@ docs/admm-convergence.md for how to read it).
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.core import algorithms, engine, expfam, network
 from repro.core import model as model_lib
 from repro.data import datasets
@@ -20,7 +21,7 @@ import sys
 sys.path.insert(0, ".")
 from benchmarks import common  # noqa: E402
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 
 def run_table(name, data, K, D, n_iters, rho, tau):

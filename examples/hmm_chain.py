@@ -14,10 +14,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import engine, expfam, network
+from repro import runtime
+from repro.core import engine, network
 from repro.models import hmm
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 K, D, N_NODES = 3, 2, 6
 

@@ -15,11 +15,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import engine, expfam, network
+from repro import runtime
+from repro.core import engine, network
 from repro.data import stream
 from repro.models import ppca
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 N_NODES, N_PER, D, Q = 6, 40, 5, 2
 

@@ -22,11 +22,12 @@ node axis, or serve many such sessions at once with
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.core import algorithms, engine, expfam, gmm, network, refperm
 from repro.core import model as model_lib
 from repro.data import synthetic
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 K, D, N_NODES, N_ITERS = 3, 2, 50, 800
 

@@ -18,11 +18,12 @@ anywhere; see docs/sparse-topologies.md):
 """
 import numpy as np
 
+from repro import runtime
 from repro.core import engine, expfam, gmm, network, refperm
 from repro.core import model as model_lib
 from repro.data import synthetic
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 N, K, D, ITERS = 1000, 3, 2, 60
 

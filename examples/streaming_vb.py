@@ -17,11 +17,12 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro import runtime
 from repro.core import algorithms, engine, expfam, gmm, network, refperm
 from repro.core import model as model_lib
 from repro.data import stream, synthetic
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 
 def main() -> None:

@@ -29,6 +29,7 @@ import sys
 
 import numpy as np
 
+from repro import runtime
 from repro import telemetry
 from repro.core import engine, expfam, network
 from repro.core import model as model_lib
@@ -36,7 +37,7 @@ from repro.data import synthetic
 from repro.serving.vb_service import VBRequest, VBService
 from repro.telemetry import taps
 
-expfam.enable_x64()
+runtime.use_platform_precision()
 
 
 def main() -> None:

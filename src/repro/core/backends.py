@@ -27,7 +27,8 @@ per-run via `run_vb(..., backend=)`, and compose with both executors: the
 fused kernel maps over whatever slice of the node axis the executor hands
 it, so under `MeshExecutor`/shard_map each shard runs the kernel on its
 local nodes.  Off-TPU the kernel executes in pallas interpret mode
-(numerics-identical); on a TPU backend the same call compiles to Mosaic.
+(numerics-identical); on a TPU the same call compiles to Mosaic
+(`kernels/ops.py` decides).
 
 Every backend is a frozen dataclass: hashable, so wrappers may pass backend
 instances through `jax.jit` static arguments.
@@ -49,8 +50,8 @@ class PrecisionPolicy(NamedTuple):
     """Dtype contract of the fused hot path.
 
     data_dtype : streaming dtype for x/mask entering the kernel (None =
-        leave as given).  bf16 halves HBM traffic on TPU; the kernel
-        upcasts blocks in VMEM.
+        leave as given, but never wider than f32).  bf16 halves HBM
+        traffic on TPU; the kernel upcasts blocks in VMEM.
     accum_dtype : dtype of the unpack/precompute and the VBM post-stage
         (statistics always accumulate in f32 inside the kernel).
     out_dtype : dtype of the returned phi* stack (None = match the
@@ -137,9 +138,14 @@ def _fused_local_vbm(x, mask, phi_nodes, prior, replication, *, K, D,
         q = expfam.unpack_natural(phi.astype(acc), K, D)
         return gmm.estep_terms(q, dtype=acc)
 
-    log_prior, Wn, b, c = jax.vmap(terms)(phi_nodes)
+    # the kernel computes in f32 and Mosaic cannot lower f64 operands, so
+    # nothing wider than f32 enters it (the kernel upcasts narrower data)
+    log_prior, Wn, b, c = (a.astype(jnp.float32)
+                           for a in jax.vmap(terms)(phi_nodes))
     if data_dtype is not None:
         x = x.astype(data_dtype)
+    elif jnp.dtype(x.dtype).itemsize > 4:
+        x = x.astype(jnp.float32)
     mask = mask.astype(x.dtype)
     # replication scaling happens kernel-side (at statistics-emit time)
     _, R, sum_x, sum_xx = ops.gmm_estep_nodes(x, mask, log_prior, Wn, b, c,

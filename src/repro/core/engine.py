@@ -88,7 +88,6 @@ import jax.numpy as jnp
 from repro import telemetry
 from repro.core import network as network_lib
 from repro.data import stream
-from repro.dist import compat
 from repro.telemetry import taps
 
 
@@ -164,7 +163,7 @@ def _ring_perms(n: int):
 
 def ring_neighbors(x: jnp.ndarray, axis_name: str):
     """(x_{i-1}, x_{i+1}) along the mesh-axis ring, via two ppermutes."""
-    fwd, bwd = _ring_perms(compat.axis_size(axis_name))
+    fwd, bwd = _ring_perms(jax.lax.axis_size(axis_name))
     return (jax.lax.ppermute(x, axis_name, fwd),
             jax.lax.ppermute(x, axis_name, bwd))
 
@@ -192,7 +191,7 @@ def ring_combine_block(varphi: jnp.ndarray, axis_name: str,
     local nodes).  Interior neighbours are a local roll; only the two
     boundary rows cross the ICI link (ppermute) — the minimal-traffic
     neighbour exchange."""
-    fwd, bwd = _ring_perms(compat.axis_size(axis_name))
+    fwd, bwd = _ring_perms(jax.lax.axis_size(axis_name))
     prev_tail = jax.lax.ppermute(varphi[-1:], axis_name, fwd)
     next_head = jax.lax.ppermute(varphi[:1], axis_name, bwd)
     shifted_right = jnp.concatenate([prev_tail, varphi[:-1]], 0)  # phi_{i-1}
@@ -586,9 +585,9 @@ class RingDiffusion(_CombineTopology):
             if not self.links.time_varying:
                 return ring_combine_block(varphi, axis, self.w_self)
             n_local = varphi.shape[0]
-            n = compat.axis_size(axis) * n_local
+            n = jax.lax.axis_size(axis) * n_local
             e = self.links.keep_ring(t, n, varphi.dtype)  # e[i]: link i,i+1
-            fwd, bwd = _ring_perms(compat.axis_size(axis))
+            fwd, bwd = _ring_perms(jax.lax.axis_size(axis))
             prev_tail = jax.lax.ppermute(varphi[-1:], axis, fwd)
             next_head = jax.lax.ppermute(varphi[:1], axis, bwd)
             left = jnp.concatenate([prev_tail, varphi[:-1]], 0)  # phi_{i-1}
@@ -1750,8 +1749,8 @@ def _run_vb_sharded(session: VBSession, n_iters, phi0, carry0, stream0, t0):
             return phi, aux, st, kls, msds, diags
         return phi, aux, st, kls, msds
 
-    fn = compat.shard_map(run, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out = fn(data, phi0,
              carry0 if has_carry else jnp.zeros((), phi0.dtype),
              stream0 if has_stream else jnp.zeros((), phi0.dtype),

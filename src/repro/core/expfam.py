@@ -97,8 +97,11 @@ class GMMPosterior(NamedTuple):
 def noninformative_prior(K: int, D: int, *, alpha0: float = 1.0,
                          beta0: float = 1.0, nu0: float | None = None,
                          w0_scale: float = 1.0, m0: jnp.ndarray | None = None,
-                         dtype=jnp.float64) -> GMMPosterior:
-    """Broad conjugate prior (paper Sec. V: 'non-informative priors')."""
+                         dtype=None) -> GMMPosterior:
+    """Broad conjugate prior (paper Sec. V: 'non-informative priors').
+    `dtype` defaults to the enabled float precision (f64 under x64)."""
+    if dtype is None:
+        dtype = jnp.result_type(float)
     if nu0 is None:
         nu0 = float(D)
     if m0 is None:
@@ -211,7 +214,13 @@ def nw_project(seg: jnp.ndarray, K: int, D: int, *,
                min_beta: float = 1e-6, min_eig: float = 1e-8) -> jnp.ndarray:
     """Projection of a flat Normal-Wishart segment onto its domain: clamps
     beta and nu and projects the W^{-1} carrier onto the PSD cone by
-    eigenvalue clipping (the closest point in Frobenius norm)."""
+    eigenvalue clipping (the closest point in Frobenius norm).
+
+    A component already in the domain comes back bit-unchanged: only the
+    coordinates of a component whose clamp or clip fired are rebuilt.  The
+    eigh round trip and the nu round trip are not exact (in f32 their
+    error at the norms VB reaches is far above ADMM's `clip_tol`), and
+    the adaptive consensus reads any change as an eigen-clip."""
     blocks = seg.reshape(K, 2 + D + D * D)
     n1 = blocks[:, 0]
     n4 = jnp.minimum(blocks[:, 1], -min_beta / 2.0)   # beta >= min_beta
@@ -219,8 +228,8 @@ def nw_project(seg: jnp.ndarray, K: int, D: int, *,
     n2 = blocks[:, 2 + D:].reshape(K, D, D)
     beta = -2.0 * n4
     m = n3 / beta[:, None]
-    nu = jnp.maximum(2.0 * n1 + D, (D - 1.0) + 1e-3)
-    n1 = (nu - D) / 2.0
+    nu_min = (D - 1.0) + 1e-3
+    n1 = jnp.where(2.0 * n1 + D < nu_min, (nu_min - D) / 2.0, n1)
     mmT = m[:, :, None] * m[:, None, :]
     W_inv = -2.0 * n2 - beta[:, None, None] * mmT
     W_inv = 0.5 * (W_inv + jnp.swapaxes(W_inv, -1, -2))
@@ -229,9 +238,11 @@ def nw_project(seg: jnp.ndarray, K: int, D: int, *,
     # an absolute 1e-8 floor would not survive the round trip at large norms
     floor = jnp.maximum(min_eig,
                         1e-10 * jnp.max(jnp.abs(eigval), -1, keepdims=True))
+    clip = jnp.any(eigval < floor, axis=-1) | (n4 != blocks[:, 1])
     eigval = jnp.maximum(eigval, floor)
     W_inv = jnp.einsum("kij,kj,klj->kil", eigvec, eigval, eigvec)
-    n2 = -0.5 * W_inv - 0.5 * beta[:, None, None] * mmT
+    n2 = jnp.where(clip[:, None, None],
+                   -0.5 * W_inv - 0.5 * beta[:, None, None] * mmT, n2)
     blocks = jnp.concatenate(
         [n1[:, None], n4[:, None], n3, n2.reshape(K, D * D)], axis=-1)
     return blocks.reshape(-1)

@@ -48,7 +48,11 @@ class NGPosterior(NamedTuple):
 
 
 def prior(D: int, *, a0: float = 1.0, b0: float = 1.0, v0: float = 1e-2,
-          dtype=jnp.float64) -> NGPosterior:
+          dtype=None) -> NGPosterior:
+    """Broad Normal-Gamma prior; `dtype` defaults to the enabled float
+    precision (f64 under x64)."""
+    if dtype is None:
+        dtype = jnp.result_type(float)
     return NGPosterior(m=jnp.zeros((D,), dtype),
                        V=jnp.eye(D, dtype=dtype) * v0,
                        a=jnp.asarray(a0, dtype), b=jnp.asarray(b0, dtype))

@@ -60,9 +60,10 @@ def ionosphere_surrogate(n_nodes: int = 20, *, seed: int = 0) -> SensorData:
 
 
 def coil20_surrogate(n_classes: int, n_nodes: int = 10, *,
-                     seed: int = 0) -> SensorData:
+                     seed: int = 0, per_class: int = 72) -> SensorData:
     """COIL-20 after PCA: 72 images per object, 52 dims.  Rotation sweeps
-    make each class an elongated low-rank cluster."""
+    make each class an elongated low-rank cluster.  `per_class` scales
+    the sample count at the same width and class structure."""
     rng = np.random.default_rng(seed)
     d = 52
     xs, ls = [], []
@@ -70,9 +71,10 @@ def coil20_surrogate(n_classes: int, n_nodes: int = 10, *,
         center = rng.normal(0.0, 2.2, d)
         # low-rank elongation (the turntable rotation manifold)
         basis = rng.normal(size=(d, 4)) * 0.9
-        t = rng.normal(size=(72, 4))
-        xs.append(center + t @ basis.T + rng.normal(0.0, 0.25, (72, d)))
-        ls.append(np.full(72, k))
+        t = rng.normal(size=(per_class, 4))
+        xs.append(center + t @ basis.T
+                  + rng.normal(0.0, 0.25, (per_class, d)))
+        ls.append(np.full(per_class, k))
     x = np.concatenate(xs)
     labels = np.concatenate(ls)
     return _to_sensor_data(x, labels, n_nodes, rng)

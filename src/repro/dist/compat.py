@@ -1,12 +1,11 @@
-"""JAX version compatibility for mesh + shard_map entry points.
+"""Mesh + shard_map entry points, and the record of which axes are manual.
 
-The codebase targets the modern spelling (``jax.shard_map`` with
-``axis_names=``/``check_vma=``, ``jax.set_mesh`` as a context manager,
-``jax.sharding.get_abstract_mesh``).  The pinned container ships an older
-JAX where the same functionality lives under ``jax.experimental.shard_map``
-(with ``auto=``/``check_rep=``) and there is no ambient-mesh setter beyond
-``with mesh:``.  Every mesh-aware call site goes through this module so the
-rest of the code can be written once.
+This module wraps ``jax.shard_map`` and ``jax.set_mesh`` only to record
+the stack of meshes entered via `use_mesh` and the axes a `shard_map`
+body is manual over, so that the LM stack's sharding constraints inside
+a body skip the manual axes (`dist/sharding.py`, `models/moe.py`).  The
+VB engine and serving driver, which place no such constraints, call
+``jax.shard_map`` directly.
 """
 from __future__ import annotations
 
@@ -15,25 +14,12 @@ import threading
 
 import jax
 
-try:  # modern JAX
-    _native_shard_map = jax.shard_map  # type: ignore[attr-defined]
-    _HAS_NATIVE = True
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-    _HAS_NATIVE = False
-
-# Partial-manual shard_map (manual over a subset of mesh axes, the rest
-# auto/GSPMD) trips an XLA SPMD-partitioner CHECK on older JAX; callers that
-# can fall back to fully-manual should consult this flag.
-PARTIAL_MANUAL_OK = _HAS_NATIVE
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=None, check_rep=None):
-    """``jax.shard_map`` with the modern kwargs on any supported JAX.
+              check_vma=None):
+    """``jax.shard_map`` that records its manual axes for the body.
 
-    ``axis_names`` marks the manual axes (the rest stay auto/GSPMD);
-    ``check_vma`` is the new name of ``check_rep``.
+    ``axis_names`` marks the manual axes (the rest stay auto/GSPMD).
     """
     names = (frozenset(axis_names) if axis_names is not None
              else frozenset(mesh.axis_names))
@@ -42,30 +28,15 @@ def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
         with manual_axes(names):
             return f(*args)
 
-    if _HAS_NATIVE:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        elif check_rep is not None:
-            kw["check_vma"] = check_rep
-        return _native_shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
     kw = {}
-    auto = frozenset(mesh.axis_names) - names
-    if auto:
-        kw["auto"] = auto
-    flag = check_vma if check_vma is not None else check_rep
-    if flag is not None:
-        kw["check_rep"] = flag
-    return _exp_shard_map(wrapped, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
+    if axis_names is not None:
+        kw["axis_names"] = set(axis_names)
+    if check_vma is not None:
+        kw["check_vma"] = check_vma
+    return jax.shard_map(wrapped, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
-# ---------------------------------------------------------------------------
-# Ambient mesh (jax.set_mesh replacement)
-# ---------------------------------------------------------------------------
 class _MeshState(threading.local):
     def __init__(self):
         self.stack = []          # meshes entered via use_mesh
@@ -77,14 +48,11 @@ _STATE = _MeshState()
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """Ambient-mesh context: the portable spelling of ``jax.set_mesh``.
-
-    Also enters ``with mesh:`` so bare-PartitionSpec sharding constraints
-    resolve on older JAX.
-    """
+    """``jax.set_mesh(mesh)`` as the ambient mesh, recorded for
+    `current_mesh`."""
     _STATE.stack.append(mesh)
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _STATE.stack.pop()
@@ -102,18 +70,8 @@ def manual_axes(names):
 
 
 def current_mesh():
-    """The ambient mesh, or None.  Sources: use_mesh() stack, then the
-    thread-resources env populated by a plain ``with mesh:`` block."""
-    if _STATE.stack:
-        return _STATE.stack[-1]
-    try:
-        from jax._src import mesh as mesh_lib
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if pm is not None and not pm.empty:
-            return pm
-    except Exception:
-        pass
-    return None
+    """The innermost mesh entered via `use_mesh`, or None."""
+    return _STATE.stack[-1] if _STATE.stack else None
 
 
 def current_manual_axes() -> frozenset:
@@ -124,15 +82,6 @@ def current_manual_axes() -> frozenset:
 
 def axis_sizes(mesh) -> dict:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named (shard_map) axis, on any supported JAX."""
-    try:
-        return jax.lax.axis_size(axis_name)  # type: ignore[attr-defined]
-    except AttributeError:
-        from jax._src import core as _core
-        return _core.axis_frame(axis_name)
 
 
 def auto_axis_sizes() -> dict:

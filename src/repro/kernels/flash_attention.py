@@ -81,9 +81,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    window: int = 0, scale: float | None = None,
+                    block_q: int = 128, block_k: int = 128):
     """q/k/v (BH, S, hd) -> (BH, S, hd)."""
     BH, S, hd = q.shape
     scale = float(scale if scale is not None else 1.0 / (hd ** 0.5))

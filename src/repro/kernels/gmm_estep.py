@@ -46,8 +46,10 @@ def _kernel_nodes(x_ref, mask_ref, lp_ref, wn_ref, b_ref, c_ref, rep_ref,
     the replication factor (Appendix A) — at its end.
     out_refs = (r_ref, stats_ref, acc_ref) or (stats_ref, acc_ref).
 
-    The per-component work runs as ROLLED `fori_loop`s over K (dynamic ref
-    slices feed each (Tb, D) @ (D, D) MXU matmul): the trace/compile cost
+    The per-component work runs as ROLLED `fori_loop`s over K (a dynamic
+    index into the Wn ref feeds each (Tb, D) @ (D, D) MXU matmul, and each
+    (D, D) second moment is added straight into its accumulator rows
+    through a `pl.ds` ref slice): the trace/compile cost
     is O(1) in K, where the original unrolled per-component matmuls made
     compile time blow up past K ~ 16 (ROADMAP item; regression-tested by
     jaxpr size in tests/test_kernels.py)."""
@@ -64,18 +66,22 @@ def _kernel_nodes(x_ref, mask_ref, lp_ref, wn_ref, b_ref, c_ref, rep_ref,
 
     x = x_ref[0].astype(jnp.float32)                     # (Tb, D)
     mask = mask_ref[0].astype(jnp.float32)               # (Tb, 1)
-    lp = lp_ref[...].reshape(1, K).astype(jnp.float32)
+    lp = lp_ref[0].astype(jnp.float32)                   # (1, K)
     bmat = b_ref[0].astype(jnp.float32)                  # (K, D)
-    cvec = c_ref[...].reshape(1, K).astype(jnp.float32)
+    cvec = c_ref[0].astype(jnp.float32)                  # (1, K)
     Tb = x.shape[0]
+    # column selector for component k: Mosaic has no dynamic lane slices
+    # or dynamic_update_slice on values, so the rolled loops read and
+    # write column k of a (Tb, K) value through this mask instead
+    k_iota = jax.lax.broadcasted_iota(jnp.int32, (Tb, K), 1)
 
     # quadratic forms: one MXU matmul per component, rolled over K
     def quad_body(k, quad):
-        Wk = wn_ref[0, pl.ds(k, 1)][0].astype(jnp.float32)   # (D, D)
+        Wk = wn_ref[0, k].astype(jnp.float32)                # (D, D)
         xW = jax.lax.dot_general(x, Wk, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         qk = jnp.sum(xW * x, axis=1, keepdims=True)          # (Tb, 1)
-        return jax.lax.dynamic_update_slice_in_dim(quad, qk, k, axis=1)
+        return quad + jnp.where(k_iota == k, qk, 0.0)
 
     quad = jax.lax.fori_loop(0, K, quad_body,
                              jnp.zeros((Tb, K), jnp.float32))
@@ -91,22 +97,24 @@ def _kernel_nodes(x_ref, mask_ref, lp_ref, wn_ref, b_ref, c_ref, rep_ref,
 
     # accumulate sufficient statistics in VMEM scratch
     # acc layout: rows [0:K] = sum_x (K, D); row-blocks K + k*D : K+(k+1)*D
-    # hold sum_xx_k (D, D); final row block holds R (K,) broadcast in col 0.
+    # hold sum_xx_k (D, D); final row block holds R (K,) in col 0.
     sum_x = jax.lax.dot_general(r, x, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (K, D)
     acc_ref[0:K, :] += sum_x
 
-    def xx_body(k, xx_all):
-        rk = jax.lax.dynamic_slice_in_dim(r, k, 1, axis=1)   # (Tb, 1)
+    def xx_body(k, carry):
+        rk = jnp.sum(jnp.where(k_iota == k, r, 0.0), axis=1,
+                     keepdims=True)                          # (Tb, 1)
         xx = jax.lax.dot_general(x * rk, x, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        return jax.lax.dynamic_update_slice_in_dim(xx_all, xx, k * D, 0)
+        acc_ref[pl.ds(K + k * D, D), :] += xx
+        return carry
 
-    xx_all = jax.lax.fori_loop(0, K, xx_body,
-                               jnp.zeros((K * D, D), jnp.float32))
-    acc_ref[K:K + K * D, :] += xx_all
-    Rk = jnp.sum(r, axis=0)                              # (K,)
-    acc_ref[K + K * D:K + K * D + K, 0:1] += Rk[:, None]
+    jax.lax.fori_loop(0, K, xx_body, 0)
+    # R as a (K, 1) column: a lane sum of r^T (an r^T @ ones matmul here
+    # trips the TPU compiler's transpose sharing with the matmuls above)
+    acc_ref[K + K * D:K + K * D + K, 0:1] += jnp.sum(r.T, axis=1,
+                                                     keepdims=True)
 
     @pl.when(ti == nt - 1)
     def _emit():
@@ -115,8 +123,8 @@ def _kernel_nodes(x_ref, mask_ref, lp_ref, wn_ref, b_ref, c_ref, rep_ref,
         stats_ref[0] = acc_ref[...] * rep_ref[0]
 
 
-def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, *, block_t: int = 512,
-                    interpret: bool = True, return_r: bool = True,
+def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, *, interpret: bool,
+                    block_t: int = 512, return_r: bool = True,
                     replication=1.0):
     """Whole-network fused VBE step: x (N, T, D), mask (N, T), per-node
     per-component terms log_prior (N, K), Wn (N, K, D, D), b (N, K, D),
@@ -127,7 +135,8 @@ def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, *, block_t: int = 512,
     happens kernel-side at statistics-emit time instead of as a separate
     post-pass.  `replication` may be a traced scalar.  With
     `return_r=False` (the engine hot path, which only needs the
-    statistics) r is None and never written to HBM.  Grid is
+    statistics) r is None and never written to HBM.  `interpret` has no
+    default: `kernels/ops.py` decides it from the platform.  Grid is
     (node, data-block) with the data axis minor, so each node's statistics
     accumulate sequentially in one VMEM scratch and are written out
     once."""
@@ -159,17 +168,17 @@ def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, *, block_t: int = 512,
         in_specs=[
             pl.BlockSpec((1, bt, D), lambda n, t: (n, t, 0)),
             pl.BlockSpec((1, bt, 1), lambda n, t: (n, t, 0)),
-            pl.BlockSpec((1, K), lambda n, t: (n, 0)),
+            pl.BlockSpec((1, 1, K), lambda n, t: (n, 0, 0)),
             pl.BlockSpec((1, K, D, D), lambda n, t: (n, 0, 0, 0)),
             pl.BlockSpec((1, K, D), lambda n, t: (n, 0, 0)),
-            pl.BlockSpec((1, K), lambda n, t: (n, 0)),
+            pl.BlockSpec((1, 1, K), lambda n, t: (n, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((rows, D), jnp.float32)],
         interpret=interpret,
-    )(x, mask[..., None], log_prior, Wn, b, c, rep)
+    )(x, mask[..., None], log_prior[:, None], Wn, b, c[:, None], rep)
     stats = out[-1]
     r = out[0][:, :T] if return_r else None
     sum_x = stats[:, 0:K, :]
@@ -178,8 +187,8 @@ def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, *, block_t: int = 512,
     return r, R, sum_x, sum_xx
 
 
-def gmm_estep(x, mask, log_prior, Wn, b, c, *, block_t: int = 512,
-              interpret: bool = True):
+def gmm_estep(x, mask, log_prior, Wn, b, c, *, interpret: bool,
+              block_t: int = 512):
     """x (T, D), mask (T,).  Returns (r (T,K), R (K,), sum_x (K,D),
     sum_xx (K,D,D)) — unreplicated stats, matching ref.gmm_estep.  The
     single-node view of `gmm_estep_nodes` (one shared kernel body)."""

@@ -1,9 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-`interpret` defaults to True off-TPU (this container is CPU-only: kernels
-are *targeted* at TPU but *validated* by executing the kernel body in
-python via pallas interpret mode).  On a real TPU backend the same calls
-compile to Mosaic.
+This module is the one place that decides how a kernel runs: on a TPU the
+calls compile to Mosaic; on any other platform the kernel body runs in
+Pallas interpret mode (the CPU test suite validates the TPU kernels that
+way).  The kernel modules themselves take `interpret` with no default.
 """
 from __future__ import annotations
 
@@ -20,7 +20,13 @@ from repro.kernels import ssd_scan as _ss
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret unless the default device is a TPU.  A
+    `jax.default_device(...)` scope counts: it is part of every jit's
+    cache key, so the same wrapper traces once per platform."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend() != "tpu"
+    return (dev if isinstance(dev, str) else dev.platform) != "tpu"
 
 
 def _instrument(name: str):

@@ -71,7 +71,7 @@ def _kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref, state_ref,
         hout_ref[0, 0] = state_ref[...]
 
 
-def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan(x, dt, A, Bm, Cm, *, interpret: bool, chunk: int = 128):
     """x (B,S,H,P), dt (B,S,H), A (H,), Bm/Cm (B,S,N).
 
     Returns (y (B,S,H,P), final_state (B,H,P,N)) — final_state layout matches

@@ -29,6 +29,92 @@ counters plus the per-bucket occupancy/padding breakdown.
 import argparse
 import os
 
+K, D = 3, 2          # the paper's Sec. V-A mixture
+
+
+def build_requests(*, sessions: int, nodes: int, per_node, budgets,
+                   taus=(), topology: str = "mixed", minibatch: int = 0,
+                   tol: float = 0.0) -> list:
+    """The fleet this launcher serves, one `VBRequest` per session.
+
+    Session i is the paper's Sec. V-A GMM (K=3, D=2) on `nodes` nodes
+    with `per_node[i % len]` points per node (generator seed i) and the
+    last point of every node left free for `push_data`; `budgets` and
+    `taus` cycle the same way.  `topology="mixed"` alternates dSVB
+    diffusion and adaptive dVB-ADMM."""
+    from repro.core import engine, expfam, network
+    from repro.core import model as model_lib
+    from repro.data import stream, synthetic
+    from repro.serving.vb_service import VBRequest
+
+    prior = expfam.noninformative_prior(K, D, beta0=0.1, w0_scale=10.0)
+    adj, _ = network.random_geometric_graph(nodes, seed=0)
+    W = network.nearest_neighbor_weights(adj)
+    mdl = model_lib.GMMModel(prior, K, D)
+    topos = {"diffusion": engine.Diffusion(W),
+             "admm": engine.ADMMConsensus(adj, adaptive_rho=True),
+             "ring": engine.RingDiffusion()}
+    order = ["diffusion", "admm"] if topology == "mixed" else [topology]
+    mb = stream.MinibatchSpec(minibatch) if minibatch else None
+    requests = []
+    for i in range(sessions):
+        data = synthetic.paper_synthetic(
+            n_nodes=nodes, n_per_node=per_node[i % len(per_node)], seed=i)
+        # leave one free slot per node so push_data has capacity
+        mask = data.mask.at[:, -1].set(0.0)
+        topo = topos[order[i % len(order)]]
+        sched = engine.Schedule()
+        if taus and getattr(topo, "uses_schedule", True):
+            sched = engine.Schedule(tau=taus[i % len(taus)])
+        requests.append(VBRequest(model=mdl, data=(data.x, mask),
+                                  topology=topo, schedule=sched,
+                                  n_iters=budgets[i % len(budgets)],
+                                  minibatch=mb, tol=tol))
+    return requests
+
+
+def serve(svc, *, push_at: int = 0) -> int:
+    """Drive `svc` until every session is done; after `push_at` slices
+    (0 = never) append one fresh point to node 0 of the first session.
+    Returns the number of slices."""
+    import numpy as np
+
+    n_slices = 0
+    while True:
+        left = svc.step_slice()
+        n_slices += 1
+        if push_at and n_slices == push_at:
+            rid0 = svc.sessions[0]
+            rng = np.random.default_rng(123)
+            svc.push_data(rid0, node=0, points=rng.normal(size=(1, D)))
+            print(f"[slice {n_slices}] pushed 1 fresh point to "
+                  f"{rid0} node 0")
+        if left == 0:
+            return n_slices
+
+
+def checkpoint_roundtrip(svc, rid: str, request, ckpt_dir: str):
+    """Save session `rid`, restore it into a fresh service, assert the
+    restored state is bit-exact, then run it one more slice.  Returns
+    (path, t at save, t after the extra slice)."""
+    import numpy as np
+
+    from repro.serving.vb_service import VBService
+
+    os.makedirs(ckpt_dir, exist_ok=True)
+    path = os.path.join(ckpt_dir, f"{rid}.npz")
+    svc.save_session(rid, path)
+    svc2 = VBService(slice_iters=svc.slice_iters)
+    rid_r = svc2.submit(request, restore_from=path)
+    st0, st_r = svc.status(rid), svc2.status(rid_r)
+    if st_r.t != st0.t or not np.array_equal(np.asarray(st0.phi),
+                                             np.asarray(st_r.phi)):
+        raise RuntimeError(f"restored {rid} differs from the saved session "
+                           f"(t {st_r.t} vs {st0.t})")
+    svc2.extend_budget(rid_r, svc.slice_iters)
+    svc2.run()
+    return path, st0.t, svc2.status(rid_r).t
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -74,100 +160,47 @@ def main():
                          "snapshot (Prometheus text format) at drain")
     args = ap.parse_args()
 
-    import numpy as np
+    from repro import runtime, telemetry
+    from repro.serving.vb_service import VBService
 
-    from repro import telemetry
-
+    runtime.use_compile_cache()
+    runtime.use_platform_precision()
     if args.trace or args.metrics:
         telemetry.enable()
 
-    from repro.core import engine, expfam, network
-    from repro.core import model as model_lib
-    from repro.data import stream, synthetic
-    from repro.serving.vb_service import VBRequest, VBService
-
-    expfam.enable_x64()
-    K, D = 3, 2
-    prior = expfam.noninformative_prior(K, D, beta0=0.1, w0_scale=10.0)
-    adj, _ = network.random_geometric_graph(args.nodes, seed=0)
-    W = network.nearest_neighbor_weights(adj)
-    mdl = model_lib.GMMModel(prior, K, D)
-    topos = {"diffusion": engine.Diffusion(W),
-             "admm": engine.ADMMConsensus(adj, adaptive_rho=True),
-             "ring": engine.RingDiffusion()}
-    order = (["diffusion", "admm"] if args.topology == "mixed"
-             else [args.topology])
-    budgets = [int(b) for b in args.budgets.split(",")]
-    minibatch = (stream.MinibatchSpec(args.minibatch)
-                 if args.minibatch else None)
-
+    requests = build_requests(
+        sessions=args.sessions, nodes=args.nodes,
+        per_node=[int(p) for p in args.per_node.split(",")],
+        budgets=[int(b) for b in args.budgets.split(",")],
+        taus=[float(t) for t in args.taus.split(",")] if args.taus else [],
+        topology=args.topology, minibatch=args.minibatch, tol=args.tol)
     arrivals = ([int(a) for a in args.arrive_at.split(",")]
                 if args.arrive_at else [0])
-    per_node = [int(p) for p in args.per_node.split(",")]
-    taus = [float(t) for t in args.taus.split(",")] if args.taus else []
     bucket = (None if args.bucket == "none"
               else "pow2" if args.bucket == "pow2" else float(args.bucket))
 
     svc = VBService(slice_iters=args.slice,
                     max_fleet=args.max_fleet or None, bucket=bucket)
-    requests = {}
-    for i in range(args.sessions):
-        data = synthetic.paper_synthetic(
-            n_nodes=args.nodes, n_per_node=per_node[i % len(per_node)],
-            seed=i)
-        # leave one free slot per node so --push-at has capacity
-        mask = data.mask.at[:, -1].set(0.0)
-        topo = topos[order[i % len(order)]]
-        sched = engine.Schedule()
-        if taus and getattr(topo, "uses_schedule", True):
-            sched = engine.Schedule(tau=taus[i % len(taus)])
-        req = VBRequest(model=mdl, data=(data.x, mask),
-                        topology=topo, schedule=sched,
-                        n_iters=budgets[i % len(budgets)],
-                        minibatch=minibatch, tol=args.tol)
+    by_rid = {}
+    for i, req in enumerate(requests):
         rid = svc.submit(req, arrive_at=arrivals[i % len(arrivals)])
-        requests[rid] = req
-
-    pushed = False
-    n_slices = 0
-    while True:
-        left = svc.step_slice()
-        n_slices += 1
-        if args.push_at and n_slices == args.push_at and not pushed:
-            rid0 = svc.sessions[0]
-            rng = np.random.default_rng(123)
-            svc.push_data(rid0, node=0, points=rng.normal(size=(1, D)))
-            pushed = True
-            print(f"[slice {n_slices}] pushed 1 fresh point to "
-                  f"{rid0} node 0")
-        if left == 0:
-            break
+        by_rid[rid] = req
+    n_slices = serve(svc, push_at=args.push_at)
 
     print(f"{'session':9s} {'topology':22s} {'iters':>6s} {'budget':>7s} "
           f"{'conv':>5s} {'final delta':>12s}")
     for rid in svc.sessions:
         st = svc.status(rid)
-        topo = type(requests[rid].topology).__name__
+        topo = type(by_rid[rid].topology).__name__
         print(f"{rid:9s} {topo:22s} {st.t:6d} {st.budget:7d} "
               f"{str(st.converged):>5s} {st.delta:12.3e}")
 
     if args.ckpt_dir:
         rid0 = svc.sessions[0]
-        os.makedirs(args.ckpt_dir, exist_ok=True)
-        path = os.path.join(args.ckpt_dir, f"{rid0}.npz")
-        svc.save_session(rid0, path)
-        # resume into a FRESH service and extend the budget a little
-        svc2 = VBService(slice_iters=args.slice)
-        rid_r = svc2.submit(requests[rid0], restore_from=path)
-        st0, st_r = svc.status(rid0), svc2.status(rid_r)
-        assert st_r.t == st0.t, (st_r.t, st0.t)
-        assert float(np.max(np.abs(np.asarray(st0.phi)
-                                   - np.asarray(st_r.phi)))) == 0.0
-        svc2.extend_budget(rid_r, args.slice)
-        svc2.run()
-        print(f"checkpoint: saved {rid0} at t={st0.t} -> {path}, "
-              f"restored bit-exact, extended to "
-              f"t={svc2.status(rid_r).t}")
+        path, t0, t1 = checkpoint_roundtrip(svc, rid0, by_rid[rid0],
+                                            args.ckpt_dir)
+        print(f"checkpoint: saved {rid0} at t={t0} -> {path}, "
+              f"restored bit-exact, extended to t={t1}")
 
     st = svc.stats()
     print(f"driver: {st.slices} slices, {st.compiles} compiles, "

@@ -66,8 +66,11 @@ class HMMPosterior(NamedTuple):
 def noninformative_prior(K: int, D: int, *, alpha0: float = 1.0,
                          trans0: float = 1.0, beta0: float = 1.0,
                          nu0: float | None = None, w0_scale: float = 1.0,
-                         dtype=jnp.float64) -> HMMPosterior:
-    """Broad conjugate prior: uniform Dirichlets + the GMM emission prior."""
+                         dtype=None) -> HMMPosterior:
+    """Broad conjugate prior: uniform Dirichlets + the GMM emission prior.
+    `dtype` defaults to the enabled float precision (f64 under x64)."""
+    if dtype is None:
+        dtype = jnp.result_type(float)
     g = expfam.noninformative_prior(K, D, alpha0=alpha0, beta0=beta0,
                                     nu0=nu0, w0_scale=w0_scale, dtype=dtype)
     return HMMPosterior(pi=g.alpha, trans=jnp.full((K, K), trans0, dtype),

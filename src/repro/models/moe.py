@@ -45,10 +45,9 @@ def moe_block(x: jnp.ndarray, p, cfg: ModelConfig):
         axes = tuple(a for a in ("pod", "data")
                      if sizes.get(a, 1) > 1
                      and x.shape[0] % sizes[a] == 0)
-        # local dispatch leaves the expert weights on auto (GSPMD) axes, a
-        # partial-manual shard_map — hard XLA CHECK failure on older JAX,
-        # so fall back to global dispatch there
-        if axes and compat.PARTIAL_MANUAL_OK:
+        # local dispatch leaves the expert weights on auto (GSPMD) axes:
+        # a partial-manual shard_map
+        if axes:
             fn = compat.shard_map(
                 functools.partial(_moe_dispatch, cfg=cfg,
                                   axis_names=axes),

@@ -45,8 +45,9 @@ from repro.core.linreg import NGPosterior
 
 
 def prior(D: int, Q: int, *, a0: float = 1.0, b0: float = 1.0,
-          v0: float = 1e-2, dtype=jnp.float64) -> NGPosterior:
-    """Row-stacked broad Normal-Gamma prior over the (D, Q) loading matrix."""
+          v0: float = 1e-2, dtype=None) -> NGPosterior:
+    """Row-stacked broad Normal-Gamma prior over the (D, Q) loading matrix
+    (`dtype`: see `linreg.prior`)."""
     one = linreg.prior(Q, a0=a0, b0=b0, v0=v0, dtype=dtype)
     return NGPosterior(
         m=jnp.broadcast_to(one.m, (D, Q)),

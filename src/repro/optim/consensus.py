@@ -29,13 +29,12 @@ import jax.numpy as jnp
 
 from repro.core.engine import (residual_balanced_rho, ring_combine,
                                ring_neighbors)
-from repro.dist import compat
 
 _ring_neighbors = ring_neighbors   # backward-compatible alias
 
 
 def ring_size(axis: str) -> int:
-    return compat.axis_size(axis)
+    return jax.lax.axis_size(axis)
 
 
 # ---------------------------------------------------------------------------

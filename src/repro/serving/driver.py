@@ -45,6 +45,7 @@ API as a thin wrapper, and `serving/engine.py`'s LM `Engine` reuses
 """
 from __future__ import annotations
 
+import contextlib
 import heapq
 import itertools
 import os
@@ -441,7 +442,7 @@ class FleetGroup:
         axis exactly as in `engine._run_vb_sharded`."""
         from jax.sharding import PartitionSpec as P
 
-        from repro.dist import compat, sharding
+        from repro.dist import sharding
 
         mesh, axis = self.executor.mesh, self.executor.axis
         ses = self.session
@@ -488,8 +489,8 @@ class FleetGroup:
             return _slice_scan(one, k)(data_l, phi_l, carry_l, st_l, t,
                                        conv, budget, tol, delta, hyper)
 
-        fn = compat.shard_map(run, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
 
         def call(data, phi, carry, st, t, conv, budget, tol, delta, hyper):
             return fn(data, phi, carry, st, t, conv, budget, tol, delta,
@@ -673,6 +674,19 @@ class VBDriver:
         self._stop_evt = threading.Event()
         self._thread: Optional[threading.Thread] = None
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """The driver lock, with the executor's mesh as the ambient mesh:
+        fleet buffers that a shard_map returns over a mesh with explicit
+        axes (`jax.make_mesh`'s default) can only be updated eagerly
+        inside `jax.set_mesh` of that mesh."""
+        with self._lock:
+            if self.executor is None:
+                yield
+            else:
+                with jax.set_mesh(self.executor.mesh):
+                    yield
+
     # -- admission --------------------------------------------------------
     def _session_key(self, model, topology, schedule, replication,
                      minibatch, data) -> tuple:
@@ -738,7 +752,7 @@ class VBDriver:
             record = ckpt.restore(restore_from, record)
         key = self._session_key(req.model, req.topology, req.schedule,
                                 req.replication, req.minibatch, data)
-        with self._lock:
+        with self._locked():
             rid = f"s{self._counter:04d}"
             self._counter += 1
             self._order.append(rid)
@@ -798,7 +812,7 @@ class VBDriver:
         per fleet with active work, overlap host-side checkpoint
         snapshots with the device slice, then sync flags, evict finished
         sessions and advance the clock.  Returns #sessions still open."""
-        with self._lock:
+        with self._locked():
             self._try_admit()
             stepped = [g for g in self._groups.values()
                        if g.active_count() > 0]
@@ -861,7 +875,7 @@ class VBDriver:
                 + len(self._queued))
 
     def remaining(self) -> int:
-        with self._lock:
+        with self._locked():
             return self._remaining_locked()
 
     def drain(self, max_slices: Optional[int] = None,
@@ -886,7 +900,7 @@ class VBDriver:
 
     def start(self) -> None:
         """Start the background scheduler thread (idempotent)."""
-        with self._lock:
+        with self._locked():
             if self._thread is not None and self._thread.is_alive():
                 return
             self._stop_evt.clear()
@@ -908,7 +922,7 @@ class VBDriver:
 
     # -- observation ------------------------------------------------------
     def status(self, rid: str) -> SessionStatus:
-        with self._lock:
+        with self._locked():
             meta = self._meta.get(rid)
             if meta is None:
                 raise KeyError(f"unknown session {rid!r}")
@@ -935,7 +949,7 @@ class VBDriver:
 
     @property
     def sessions(self) -> list[str]:
-        with self._lock:
+        with self._locked():
             return list(self._order)
 
     def _bucket_stats(self) -> tuple:
@@ -958,7 +972,7 @@ class VBDriver:
         return tuple(sorted(out, key=lambda b: b.label))
 
     def stats(self) -> DriverStats:
-        with self._lock:
+        with self._locked():
             active = sum(g.active_count() for g in self._groups.values())
             capacity = sum(g.capacity for g in self._groups.values())
             compiles = sum(g.compiles for g in self._groups.values())
@@ -987,7 +1001,7 @@ class VBDriver:
         next ladder rung that fits, and it re-enters the queue under the
         larger bucket's group key — same absolute-t resume contract, so
         the trajectory matches a solo run on the regrown buffers."""
-        with self._lock:
+        with self._locked():
             if rid in self._where:
                 key, i = self._where[rid]
                 g = self._groups[key]
@@ -1066,7 +1080,7 @@ class VBDriver:
     def replace_data(self, rid: str, data: Any) -> None:
         """Replace a session's data buffers wholesale (same shapes; a
         bucketed session accepts any data that pads to its rung)."""
-        with self._lock:
+        with self._locked():
             bucket = self._meta.get(rid, {}).get("bucket")
             if bucket is not None:
                 if rid in self._where:
@@ -1110,7 +1124,7 @@ class VBDriver:
         raise KeyError(f"unknown session {rid!r}")
 
     def extend_budget(self, rid: str, extra_iters: int) -> None:
-        with self._lock:
+        with self._locked():
             if rid in self._where:
                 key, i = self._where[rid]
                 g = self._groups[key]
@@ -1156,7 +1170,7 @@ class VBDriver:
         `wait=False` the device→host transfer and compression happen on
         the background writer thread (call `flush_checkpoints` or rely
         on `drain` before reading the file)."""
-        with self._lock:
+        with self._locked():
             if rid in self._where:
                 key, i = self._where[rid]
                 tree = self._groups[key].state_tree(i)

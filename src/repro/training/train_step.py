@@ -247,12 +247,8 @@ def _consensus_step(cfg, mesh: Mesh, dp_mode: str, axis: str, hyper,
                                  "admm_primal_resid": 0,
                                  "admm_dual_resid": 0,
                                  "admm_rho": 0}, rep))
-        # Partial-manual (auto "model" axis) where supported; otherwise run
-        # fully manual — params replicate over "model" inside the body,
-        # which is numerically identical (redundant compute per model
-        # shard) and avoids the old-XLA partitioner CHECK.
-        names = {axis} if compat.PARTIAL_MANUAL_OK else None
-        fn = compat.shard_map(inner, mesh=mesh, axis_names=names,
+        # partial-manual: the "model" axis stays auto (GSPMD)
+        fn = compat.shard_map(inner, mesh=mesh, axis_names={axis},
                               in_specs=in_specs, out_specs=out_specs,
                               check_vma=False)
         p, o, d, rho_new, metrics = fn(state.params, state.opt, state.duals,

@@ -164,8 +164,10 @@ def test_gmm_estep_kernel_replication_scaling():
     """Kernel-side replication: stats scale by the factor, r does not."""
     args = _gmm_node_args(N=2, T=50, K=3, D=2)
     from repro.kernels import gmm_estep as ge
-    r1, R1, sx1, sxx1 = ge.gmm_estep_nodes(*args, replication=1.0)
-    r8, R8, sx8, sxx8 = ge.gmm_estep_nodes(*args, replication=8.0)
+    r1, R1, sx1, sxx1 = ge.gmm_estep_nodes(*args, replication=1.0,
+                                           interpret=True)
+    r8, R8, sx8, sxx8 = ge.gmm_estep_nodes(*args, replication=8.0,
+                                           interpret=True)
     np.testing.assert_array_equal(np.asarray(r1), np.asarray(r8))
     np.testing.assert_allclose(np.asarray(R8), 8.0 * np.asarray(R1),
                                rtol=1e-6)
