@@ -140,18 +140,19 @@ def _fused_local_vbm(x, mask, phi_nodes, prior, replication, *, K, D,
 
     # the kernel computes in f32 and Mosaic cannot lower f64 operands, so
     # nothing wider than f32 enters it (the kernel upcasts narrower data)
-    log_prior, Wn, b, c = (a.astype(jnp.float32)
-                           for a in jax.vmap(terms)(phi_nodes))
-    if data_dtype is not None:
-        x = x.astype(data_dtype)
-    elif jnp.dtype(x.dtype).itemsize > 4:
-        x = x.astype(jnp.float32)
-    mask = mask.astype(x.dtype)
-    # replication scaling happens kernel-side (at statistics-emit time)
-    _, R, sum_x, sum_xx = ops.gmm_estep_nodes(x, mask, log_prior, Wn, b, c,
-                                              replication,
-                                              block_t=block_t,
-                                              return_r=False)
+    with jax.named_scope("vb/terms"):
+        log_prior, Wn, b, c = (a.astype(jnp.float32)
+                               for a in jax.vmap(terms)(phi_nodes))
+    with jax.named_scope("vb/vbe"):
+        if data_dtype is not None:
+            x = x.astype(data_dtype)
+        elif jnp.dtype(x.dtype).itemsize > 4:
+            x = x.astype(jnp.float32)
+        mask = mask.astype(x.dtype)
+        # replication scaling happens kernel-side (at statistics-emit time)
+        _, R, sum_x, sum_xx = ops.gmm_estep_nodes(
+            x, mask, log_prior, Wn, b, c, replication, block_t=block_t,
+            return_r=False)
 
     # fused post-stage: Appendix-A VBM update + pack
     prior_acc = jax.tree_util.tree_map(lambda a: a.astype(acc), prior)
@@ -161,7 +162,8 @@ def _fused_local_vbm(x, mask, phi_nodes, prior, replication, *, K, D,
                               sum_xx=sxx_i.astype(acc))
         return expfam.pack_natural(gmm.posterior_from_stats(stats, prior_acc))
 
-    return jax.vmap(post)(R, sum_x, sum_xx).astype(out)
+    with jax.named_scope("vb/vbm"):
+        return jax.vmap(post)(R, sum_x, sum_xx).astype(out)
 
 
 @dataclasses.dataclass(frozen=True)

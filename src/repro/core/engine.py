@@ -1022,7 +1022,8 @@ class ADMMConsensus:
                        + rho * (deg[:, None] * phi + neigh_sum(phi)))
             phi_hat = phi_hat / (1.0 + 2.0 * rho * deg)[:, None]
             if self.project:
-                phi_new = jax.vmap(model.project_to_domain)(phi_hat)  # (38b)
+                with jax.named_scope("vb/project"):             # (38b)
+                    phi_new = jax.vmap(model.project_to_domain)(phi_hat)
             else:
                 phi_new = phi_hat
             # (39) dual ascent with the kappa_t ramp (40)
@@ -1067,7 +1068,8 @@ class ADMMConsensus:
                    + rho_coord * (deg[:, None] * phi + neigh_sum(phi)))
         phi_hat = phi_hat / (1.0 + 2.0 * rho_coord * deg[:, None])
         if self.project:
-            phi_new = jax.vmap(model.project_to_domain)(phi_hat)  # (38b)
+            with jax.named_scope("vb/project"):             # (38b)
+                phi_new = jax.vmap(model.project_to_domain)(phi_hat)
         else:
             phi_new = phi_hat
         clip_active = (jnp.max(jnp.abs(phi_new - phi_hat), axis=1)
@@ -1427,9 +1429,10 @@ def _iteration(model, data, base_mask, topology, schedule, replication,
                     + anchor_full)
     else:
         phi_star = model.local_optimum(data_t, phi, replication)
-    phi_new, carry_new, diag = topology.step(model, phi, carry, phi_star, t,
-                                             schedule, axis=axis,
-                                             local=local, hyper=hyper)
+    with jax.named_scope("vb/combine"):
+        phi_new, carry_new, diag = topology.step(
+            model, phi, carry, phi_star, t, schedule, axis=axis,
+            local=local, hyper=hyper)
     return phi_new, carry_new, st_new, diag
 
 

@@ -131,10 +131,12 @@ def local_vbm_optimum(x: jnp.ndarray, q_global: GMMPosterior,
 
     Returns the flat natural-parameter message of Eq. 45.
     """
-    r = responsibilities(x, q_global, mask)
-    stats = sufficient_stats(x, r, replication)
-    q_star = posterior_from_stats(stats, prior)
-    return expfam.pack_natural(q_star)
+    with jax.named_scope("vb/vbe"):
+        r = responsibilities(x, q_global, mask)
+        stats = sufficient_stats(x, r, replication)
+    with jax.named_scope("vb/vbm"):
+        q_star = posterior_from_stats(stats, prior)
+        return expfam.pack_natural(q_star)
 
 
 # vmapped over a leading node axis: x (Nnodes, Ni, D), phi (Nnodes, P)
@@ -143,7 +145,8 @@ def local_vbm_optimum_nodes(x: jnp.ndarray, phi: jnp.ndarray,
                             K: int, D: int,
                             mask: jnp.ndarray | None = None) -> jnp.ndarray:
     def one(xi, phii, mi):
-        q = expfam.unpack_natural(phii, K, D)
+        with jax.named_scope("vb/terms"):
+            q = expfam.unpack_natural(phii, K, D)
         return local_vbm_optimum(xi, q, prior, replication, mi)
 
     if mask is None:

@@ -8,12 +8,10 @@ way).  The kernel modules themselves take `interpret` with no default.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
 
-from repro import telemetry
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gmm_estep as _ge
 from repro.kernels import ssd_scan as _ss
@@ -29,32 +27,6 @@ def _default_interpret() -> bool:
     return (dev if isinstance(dev, str) else dev.platform) != "tpu"
 
 
-def _instrument(name: str):
-    """Kernel wall-time telemetry: a `kernel_wall_seconds{kernel=...}`
-    histogram plus a `kernel/<name>` trace span per eager call.  One bool
-    check when telemetry is disabled.  Calls from inside an outer trace
-    (e.g. `core.backends._fused_local_vbm` jits around `gmm_estep_nodes`)
-    pass straight through — timing a trace is meaningless and
-    `block_until_ready` does not apply to tracers."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            if not telemetry.enabled() or any(
-                    isinstance(leaf, jax.core.Tracer) for leaf in
-                    jax.tree_util.tree_leaves((args, kwargs))):
-                return fn(*args, **kwargs)
-            t0 = time.perf_counter()
-            with telemetry.span(f"kernel/{name}"):
-                out = fn(*args, **kwargs)
-                jax.block_until_ready(out)
-            telemetry.observe("kernel_wall_seconds",
-                              time.perf_counter() - t0, kernel=name)
-            return out
-        return wrapper
-    return deco
-
-
-@_instrument("flash_attention")
 @functools.partial(jax.jit,
                    static_argnames=("causal", "window", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -73,7 +45,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return jnp.moveaxis(out.reshape(B, Hq, S, hd), 1, 2)
 
 
-@_instrument("ssd_scan")
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128):
     """Mamba-2 SSD: x (B,S,H,P), dt (B,S,H), A (H,), Bm/Cm (B,S,N)."""
@@ -81,14 +52,12 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128):
                         interpret=_default_interpret())
 
 
-@_instrument("gmm_estep")
 @functools.partial(jax.jit, static_argnames=("block_t",))
 def gmm_estep(x, mask, log_prior, Wn, b, c, *, block_t: int = 512):
     return _ge.gmm_estep(x, mask, log_prior, Wn, b, c, block_t=block_t,
                          interpret=_default_interpret())
 
 
-@_instrument("gmm_estep_nodes")
 @functools.partial(jax.jit, static_argnames=("block_t", "return_r"))
 def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, replication=1.0, *,
                     block_t: int = 512, return_r: bool = True):
@@ -102,7 +71,6 @@ def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, replication=1.0, *,
                                return_r=return_r, replication=replication)
 
 
-@_instrument("gmm_estep_from_posterior")
 @functools.partial(jax.jit, static_argnames=("block_t", "compute_dtype"))
 def gmm_estep_from_posterior(x, mask, q, *, block_t: int = 512,
                              compute_dtype=None):

@@ -407,11 +407,12 @@ class FleetGroup:
                 return None
             self._grow()
             slot = self.slots.alloc(rid)
-        self.load_state_tree(slot, record)
-        self.host_t[slot] = int(record["t"])
-        self.host_conv[slot] = bool(np.asarray(record["conv"]))
-        self.host_budget[slot] = int(record["budget"])
-        self.host_delta[slot] = float(record["delta"])
+        with telemetry.span("driver/admit", rid=rid, slot=slot):
+            self.load_state_tree(slot, record)
+            self.host_t[slot] = int(record["t"])
+            self.host_conv[slot] = bool(np.asarray(record["conv"]))
+            self.host_budget[slot] = int(record["budget"])
+            self.host_delta[slot] = float(record["delta"])
         return slot
 
     def evict(self, slot: int) -> dict:
@@ -735,36 +736,37 @@ class VBDriver:
         resuming it bit-exactly."""
         if req.n_iters < 1:
             raise ValueError(f"n_iters must be >= 1: {req.n_iters}")
-        data, bucket = self._bucket_plan(req)
-        state = engine.vb_init(
-            req.model, data, req.topology, schedule=req.schedule,
-            replication=req.replication, init_phi=req.init_phi,
-            minibatch=req.minibatch, diagnostics=False)
-        dt = state.phi.dtype
-        record = dict(phi=state.phi, t=state.t, carry=state.carry,
-                      stream=state.stream, conv=jnp.zeros((), bool),
-                      budget=jnp.asarray(req.n_iters, state.t.dtype),
-                      tol=jnp.asarray(req.tol, dt),
-                      delta=jnp.zeros((), dt), data=state.session.data,
-                      hyper=engine.session_hyper(req.topology,
-                                                 req.schedule, dt))
-        if restore_from is not None:
-            record = ckpt.restore(restore_from, record)
-        key = self._session_key(req.model, req.topology, req.schedule,
-                                req.replication, req.minibatch, data)
-        with self._locked():
-            rid = f"s{self._counter:04d}"
-            self._counter += 1
-            self._order.append(rid)
-            at = self._clock if arrive_at is None else int(arrive_at)
-            self._meta[rid] = dict(submitted=time.monotonic(),
-                                   finished=None, arrive_at=at,
-                                   bucket=bucket)
-            entry = dict(rid=rid, key=key, session=state.session,
-                         record=record, bucket=bucket)
-            self._queued[rid] = entry
-            self._queue.push(entry, at)
-            self._try_admit()
+        with telemetry.span("driver/submit"):
+            data, bucket = self._bucket_plan(req)
+            state = engine.vb_init(
+                req.model, data, req.topology, schedule=req.schedule,
+                replication=req.replication, init_phi=req.init_phi,
+                minibatch=req.minibatch, diagnostics=False)
+            dt = state.phi.dtype
+            record = dict(phi=state.phi, t=state.t, carry=state.carry,
+                          stream=state.stream, conv=jnp.zeros((), bool),
+                          budget=jnp.asarray(req.n_iters, state.t.dtype),
+                          tol=jnp.asarray(req.tol, dt),
+                          delta=jnp.zeros((), dt), data=state.session.data,
+                          hyper=engine.session_hyper(req.topology,
+                                                     req.schedule, dt))
+            if restore_from is not None:
+                record = ckpt.restore(restore_from, record)
+            key = self._session_key(req.model, req.topology, req.schedule,
+                                    req.replication, req.minibatch, data)
+            with self._locked():
+                rid = f"s{self._counter:04d}"
+                self._counter += 1
+                self._order.append(rid)
+                at = self._clock if arrive_at is None else int(arrive_at)
+                self._meta[rid] = dict(submitted=time.monotonic(),
+                                       finished=None, arrive_at=at,
+                                       bucket=bucket)
+                entry = dict(rid=rid, key=key, session=state.session,
+                             record=record, bucket=bucket)
+                self._queued[rid] = entry
+                self._queue.push(entry, at)
+                self._try_admit()
         self._wake.set()
         return rid
 
@@ -797,7 +799,6 @@ class VBDriver:
             self._n_admitted += 1
             group.n_admitted += 1
             telemetry.inc("driver_admitted_total")
-            telemetry.instant("driver/admit", rid=rid, slot=slot)
             if bucket is not None:
                 group.pad_frac_sum += (bucket[1] - bucket[0]) / bucket[1]
 
@@ -812,7 +813,7 @@ class VBDriver:
         per fleet with active work, overlap host-side checkpoint
         snapshots with the device slice, then sync flags, evict finished
         sessions and advance the clock.  Returns #sessions still open."""
-        with self._locked():
+        with self._locked(), telemetry.span("driver/tick"):
             self._try_admit()
             stepped = [g for g in self._groups.values()
                        if g.active_count() > 0]
@@ -861,12 +862,13 @@ class VBDriver:
                 continue
             done = group.done_mask()
             for slot, rid in group.slots.occupied():
-                if done[slot]:
+                if not done[slot]:
+                    continue
+                with telemetry.span("driver/evict", rid=rid, slot=slot):
                     record = group.evict(slot)
                     del self._where[rid]
                     self._n_evicted += 1
                     telemetry.inc("driver_evicted_total")
-                    telemetry.instant("driver/evict", rid=rid, slot=slot)
                     self._retire(rid, dict(record=record, key=key,
                                            session=group.session))
 

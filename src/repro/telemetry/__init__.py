@@ -7,22 +7,32 @@ One switch, three layers:
   default registry lives here; instrumented layers record through the
   module-level helpers below.
 - **Tracing** (`tracing.Tracer`): `span(name)` / `instant(name)` events
-  with Chrome-trace export — driver slices, compiles, checkpoint
-  writes, and admission/rebucket decisions on one timeline.
+  with Chrome-trace export — driver ticks, submits, admissions,
+  evictions, slices, compiles and checkpoint writes on one timeline.
+  While enabled, every span also enters a
+  `jax.profiler.TraceAnnotation` of the same name (args as stats, not
+  in the name), so a profiler trace shows the program's spans on its
+  host plane, on the device ops' clock.
 - **Taps** (`taps`): jit-safe per-iteration series out of compiled VB
   steps.  Device-side `taps.tap(...)` insertion has its OWN switch
   (`taps.enable()`) because inserting an `io_callback` changes the
   jaxpr and forces a recompile; everything else here is host-side only
   and can never change a compiled program.
 
+A `jax.monitoring` listener registered at import counts backend
+compiles while enabled: `jax_compiles_total{fun=...}` and a
+`compile/<fun>` complete event ending when the compile ended.  The
+compiled VB step names its regions with `jax.named_scope` (`vb/terms`,
+`vb/vbe`, `vb/vbm`, `vb/combine`, `vb/project`); scopes only change op
+metadata, so they are unconditional and not part of this switch.
+
 Disabled (the default) must be free: every helper below is a single
 module-bool check before touching any registry/tracer state, so
-instrumented hot paths (driver tick, kernel wrappers, `vb_run`) cost
-one branch when telemetry is off.  `tests/test_telemetry.py` pins that
-the `vb_step` jaxpr and driver compile counts are byte-identical with
-telemetry disabled, and `tools/bench_gate.py` enforces the
-`vb_driver_poisson` row so the disabled-path overhead stays
-unmeasurable.
+instrumented hot paths (driver tick, submit, admit, evict, `vb_run`)
+cost one branch when telemetry is off.  `tests/test_telemetry.py` pins
+that the `vb_step` jaxpr and driver compile counts are byte-identical
+with telemetry disabled; the benchmark's untraced runs (telemetry off)
+bound what the disabled path costs end to end.
 
 Typical use (see docs/observability.md for the catalogue)::
 
@@ -37,6 +47,8 @@ Typical use (see docs/observability.md for the catalogue)::
 from __future__ import annotations
 
 from contextlib import contextmanager, nullcontext
+
+import jax
 
 from . import taps
 from .metrics import DEFAULT_BUCKETS, MetricsRegistry
@@ -121,11 +133,19 @@ def observe(name: str, value: float, **labels) -> None:
 
 
 def span(name: str, **args):
-    """Context manager: a Chrome-trace complete event, or a shared null
-    context when disabled (one bool check, zero allocation)."""
+    """Context manager: a Chrome-trace complete event and a profiler
+    annotation of the same name, or a shared null context when disabled
+    (one bool check, zero allocation)."""
     if _ENABLED:
-        return _TRACER.span(name, **args)
+        return _annotated_span(name, args)
     return _NULL_CONTEXT
+
+
+@contextmanager
+def _annotated_span(name: str, args: dict):
+    with _TRACER.span(name, **args), \
+            jax.profiler.TraceAnnotation(name, **args):
+        yield
 
 
 def instant(name: str, **args) -> None:
@@ -147,6 +167,23 @@ def warn_once(key: str, message: str, category=UserWarning,
     import warnings
     warnings.warn(message, category, stacklevel=stacklevel + 1)
     return True
+
+
+# -- compile counter ------------------------------------------------------
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    """`jax.monitoring` duration listener: one backend compile (a
+    persistent-cache retrieval counts too) ends now."""
+    if _ENABLED and event == _COMPILE_EVENT:
+        fun = kw.get("fun_name", "?")
+        _REGISTRY.counter("jax_compiles_total", fun=fun).inc()
+        dur = duration * 1e6
+        _TRACER.complete(f"compile/{fun}", _TRACER.now_us() - dur, dur)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 # -- export ---------------------------------------------------------------

@@ -5,9 +5,10 @@ event (Chrome trace phase ``X``) with microsecond start/duration; nested
 spans on the same thread render as a flame stack in ``chrome://tracing``
 or Perfetto because the viewer nests by time containment per
 (pid, tid).  `instant(name, **args)` drops a zero-duration marker
-(phase ``i``) — used for admission / rebucket / eviction decisions that
-have no meaningful duration but should be visible on the timeline next
-to the slice spans that surround them.
+(phase ``i``) — used for requeue / rebucket decisions that have no
+meaningful duration but should be visible on the timeline next to the
+slice spans that surround them.  `complete(name, ts, dur)` records a
+span measured elsewhere (a compile, reported when it ends).
 
 Like the metrics registry, recording is thread-safe (the driver's
 scheduler loop, the `CheckpointWriter` daemon thread, and the caller's
@@ -73,6 +74,15 @@ class Tracer:
             if args:
                 ev["args"] = args
             self._record(ev)
+
+    def complete(self, name: str, ts: float, dur: float, **args) -> None:
+        """Record a complete event measured elsewhere (`ts`, `dur` in
+        microseconds on this tracer's clock, `now_us`)."""
+        ev = {"name": name, "ph": "X", "ts": ts, "dur": dur,
+              "pid": self._pid, "tid": threading.get_ident()}
+        if args:
+            ev["args"] = args
+        self._record(ev)
 
     def instant(self, name: str, **args) -> None:
         """Record a zero-duration marker (thread-scoped instant event)."""

@@ -19,6 +19,7 @@ the bench gate's pass-on-baseline / fail-on-degraded behavior.
 import importlib.util
 import json
 import os
+import re
 import threading
 import warnings
 
@@ -332,6 +333,104 @@ def test_disabled_driver_leaves_no_telemetry(setup, tmp_path):
     assert st.compiles == 1
     assert len(telemetry.registry()) == 0
     assert len(telemetry.tracer()) == 0
+
+
+# ---------------------------------------------------------------------------
+# Program spans on the profiler trace, named scopes, compile counter
+# ---------------------------------------------------------------------------
+DRIVER_SPANS = {"driver/tick", "driver/submit", "driver/admit",
+                "driver/evict", "driver/slice", "driver/sync"}
+
+
+def _profiled_fleet(mdl, W, trace_dir):
+    """A small driver run under the profiler; the host plane's events
+    (thread, name, start_ns, end_ns) as `bench.trace.load` reads them."""
+    import sys
+    sys.path.insert(0, ROOT)
+    from bench import trace as trace_lib
+
+    jax.profiler.start_trace(trace_dir)
+    try:
+        _run_fleet(mdl, W, trace_dir, ckpt=False)
+    finally:
+        jax.profiler.stop_trace()
+    return trace_lib.load(trace_dir)["host"]
+
+
+def test_driver_spans_land_on_the_profiler_trace(setup, tmp_path):
+    """Enabled spans enter a TraceAnnotation named exactly as the span
+    (args become stats, not part of the name), so they sit on the xplane
+    host plane on the device clock; disabled, none appear."""
+    mdl, adj, W, data = setup
+    with telemetry.enabled_scope():
+        host = _profiled_fleet(mdl, W, str(tmp_path / "on"))
+    names = {n for _, n, _, _ in host}
+    assert DRIVER_SPANS <= names
+    assert not any(n.startswith("driver/") and n not in DRIVER_SPANS
+                   | {"driver/compile"} for n in names)
+    ticks = [(th, s, e) for th, n, s, e in host if n == "driver/tick"]
+    slices = [(th, s, e) for th, n, s, e in host if n == "driver/slice"]
+    assert slices and all(any(th == tt and ts <= s and e <= te
+                              for tt, ts, te in ticks)
+                          for th, s, e in slices)
+    # the Tracer still holds them, for tick_gap_ms and Chrome export
+    assert DRIVER_SPANS <= set(telemetry.tracer().span_names())
+
+    telemetry.reset()
+    host = _profiled_fleet(mdl, W, str(tmp_path / "off"))
+    assert not any(n.startswith("driver/") for _, n, _, _ in host)
+
+
+def _slice_program_text(mdl, topo):
+    """The driver's compiled slice program as HLO text; its ops carry the
+    scope path in `metadata={op_name=...}`."""
+    d = drv.VBDriver(slice_iters=2, max_fleet=2)
+    for s in range(2):
+        x = synthetic.paper_synthetic(n_nodes=N_NODES, n_per_node=10,
+                                      seed=s)
+        d.submit(VBRequest(model=mdl, data=(x.x, x.mask), topology=topo,
+                           n_iters=4))
+    g = next(iter(d._groups.values()))
+    return g._slice_fn(2).lower(
+        g.data, g.phi, g.carry, g.stream, g.t, g.conv, g.budget, g.tol,
+        g.delta, g.hyper).compile().as_text()
+
+
+@pytest.mark.parametrize("backend", ["reference", "fused"])
+def test_slice_program_carries_named_scopes(setup, backend):
+    mdl, adj, W, data = setup
+    prior = expfam.noninformative_prior(K, D, beta0=0.1, w0_scale=10.0)
+    mdl = model_lib.GMMModel(prior, K, D, backend=backend)
+    # op_name paths: "jit(slice_fn)/while/body/.../vmap(vb/vbm)/jit(inv)/..."
+    def scopes(topo):
+        text = _slice_program_text(mdl, topo)
+        return set(re.findall(r"vb/[a-z]+(?=[/)])", text))
+
+    assert scopes(engine.Diffusion(W)) == {"vb/terms", "vb/vbe", "vb/vbm",
+                                           "vb/combine"}
+    assert "vb/project" in scopes(engine.ADMMConsensus(adj))
+
+
+def test_compile_counter_counts_a_fresh_jit_once():
+    def _tripled(x):
+        return 3 * x + 1
+
+    def _halved(x):
+        return x / 2
+
+    f, g = jax.jit(_tripled), jax.jit(_halved)
+    g(np.arange(3.0))                    # disabled: not counted
+    with telemetry.enabled_scope():
+        f(np.arange(3.0))
+        f(np.arange(3.0))
+        g(np.arange(3.0))
+    rows = {r["labels"]["fun"]: r["value"] for r in telemetry.snapshot()
+            if r["name"] == "jax_compiles_total"}
+    assert rows == {"jit(_tripled)": 1.0}
+    (ev,) = [e for e in telemetry.tracer().events
+             if e["name"] == "compile/jit(_tripled)"]
+    assert ev["ph"] == "X" and ev["dur"] > 0
+    assert ev["ts"] + ev["dur"] <= telemetry.tracer().now_us()
 
 
 # ---------------------------------------------------------------------------
