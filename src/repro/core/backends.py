@@ -15,9 +15,11 @@ phi is backend-invariant, only the arithmetic that produces phi* varies):
     2. run the node-batched single-pass Pallas kernel
        (kernels/gmm_estep.gmm_estep_nodes): responsibilities + sufficient
        statistics in ONE sweep over the data, f32 accumulation,
-    3. a fused post-stage — replication scaling + the Appendix-A VBM
-       hyperparameter update (gmm.posterior_from_stats) + expfam.pack_natural
-       — all inside the same jit.
+    3. a fused post-stage — the Appendix-A VBM hyperparameter update
+       packed straight into the message (gmm.natural_from_stats) — inside
+       the same jit.
+  Each node's W^{-1} is factored once per call, in step 1 (W and log|W|
+  from one LU); the post-stage inverts nothing per node.
   Data may stream in a narrow dtype (`PrecisionPolicy.data_dtype=bf16`)
   while accumulation stays f32, mirroring `ring_combine`'s `compute_dtype`
   convention.
@@ -135,8 +137,8 @@ def _fused_local_vbm(x, mask, phi_nodes, prior, replication, *, K, D,
     out = out_dtype if out_dtype is not None else phi_nodes.dtype
 
     def terms(phi):
-        q = expfam.unpack_natural(phi.astype(acc), K, D)
-        return gmm.estep_terms(q, dtype=acc)
+        q, logdet_W = expfam.unpack_natural_logdet(phi.astype(acc), K, D)
+        return gmm.estep_terms(q, dtype=acc, logdet_W=logdet_W)
 
     # the kernel computes in f32 and Mosaic cannot lower f64 operands, so
     # nothing wider than f32 enters it (the kernel upcasts narrower data)
@@ -160,7 +162,7 @@ def _fused_local_vbm(x, mask, phi_nodes, prior, replication, *, K, D,
     def post(R_i, sx_i, sxx_i):
         stats = gmm.SuffStats(R=R_i.astype(acc), sum_x=sx_i.astype(acc),
                               sum_xx=sxx_i.astype(acc))
-        return expfam.pack_natural(gmm.posterior_from_stats(stats, prior_acc))
+        return gmm.natural_from_stats(stats, prior_acc)
 
     with jax.named_scope("vb/vbm"):
         return jax.vmap(post)(R, sum_x, sum_xx).astype(out)

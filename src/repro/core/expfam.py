@@ -165,25 +165,55 @@ def block_labels(K: int, D: int):
     return np.asarray([0] * K + per * K, np.int32)
 
 
-def nw_pack(q) -> jnp.ndarray:
-    """Normal-Wishart bank -> its flat natural-parameter segment: the
-    per-component [n1, n4, n3, vec(n2)] blocks of Eq. 45, flattened.
-    Accepts an `NWParams` or a `GMMPosterior` (only m/beta/W/nu are read).
-    """
-    K, D = q.beta.shape[-1], q.m.shape[-1]
-    n1 = (q.nu - D) / 2.0                                            # (K,)
-    n4 = -q.beta / 2.0                                               # (K,)
-    n3 = q.beta[:, None] * q.m                                       # (K, D)
-    W_inv = jnp.linalg.inv(q.W)                                      # (K, D, D)
-    mmT = q.m[:, :, None] * q.m[:, None, :]
-    n2 = -0.5 * W_inv - 0.5 * q.beta[:, None, None] * mmT            # (K, D, D)
+def inv_logabsdet(A: jnp.ndarray):
+    """(inv(A), log|det A|) over the trailing two axes from ONE LU
+    factorisation P A = L U: the inverse by two triangular solves against
+    P, the log-determinant from U's diagonal.  The factorisation's own
+    permutation is used (the TPU's LU returns it): going through the
+    pivots, as `jax.scipy.linalg.lu_solve` does, adds a serial loop over
+    the rows.  (Cholesky, valid for the SPD W^{-1} this serves, was faster
+    on a TPU v5e but moved the D=2 serving cell's answers further from the
+    reference; PERF.md.)"""
+    lu, _, perm = jax.lax.linalg.lu(A)
+    P = (perm[..., :, None] == jnp.arange(A.shape[-1])).astype(A.dtype)
+    y = jax.lax.linalg.triangular_solve(lu, P, left_side=True, lower=True,
+                                        unit_diagonal=True)
+    inv = jax.lax.linalg.triangular_solve(lu, y, left_side=True, lower=False)
+    logabsdet = jnp.sum(
+        jnp.log(jnp.abs(jnp.diagonal(lu, axis1=-2, axis2=-1))), -1)
+    return inv, logabsdet
+
+
+def nw_pack_winv(m, beta, W_inv, nu) -> jnp.ndarray:
+    """The packing core: Normal-Wishart hyperparameters, with the scale
+    matrix given by its INVERSE W^{-1}, -> the per-component
+    [n1, n4, n3, vec(n2)] blocks of Eq. 45, flattened.  n2 carries W^{-1}
+    itself, so a caller that holds W^{-1} (the VBM update builds it)
+    packs without inverting anything."""
+    K, D = beta.shape[-1], m.shape[-1]
+    n1 = (nu - D) / 2.0                                              # (K,)
+    n4 = -beta / 2.0                                                 # (K,)
+    n3 = beta[:, None] * m                                           # (K, D)
+    mmT = m[:, :, None] * m[:, None, :]
+    n2 = -0.5 * W_inv - 0.5 * beta[:, None, None] * mmT              # (K, D, D)
     blocks = jnp.concatenate(
         [n1[:, None], n4[:, None], n3, n2.reshape(K, D * D)], axis=-1)
     return blocks.reshape(-1)
 
 
-def nw_unpack(seg: jnp.ndarray, K: int, D: int) -> NWParams:
-    """Flat Normal-Wishart segment -> NWParams (inverse of `nw_pack`)."""
+def nw_pack(q) -> jnp.ndarray:
+    """Normal-Wishart bank -> its flat natural-parameter segment (Eq. 45).
+    Accepts an `NWParams` or a `GMMPosterior` (only m/beta/W/nu are read);
+    inverts W once and calls `nw_pack_winv`."""
+    return nw_pack_winv(q.m, q.beta, jnp.linalg.inv(q.W), q.nu)
+
+
+def nw_unpack_logdet(seg: jnp.ndarray, K: int, D: int):
+    """Flat Normal-Wishart segment -> (NWParams, log|W| (K,)).
+
+    One factorisation of the carried W^{-1} gives both W and log|W| =
+    -log|W^{-1}|, so the E-step's E[ln|Lambda|] needs no second one
+    (`wishart_expected_logdet(..., logdet_W=)`)."""
     blocks = seg.reshape(K, 2 + D + D * D)
     n1 = blocks[:, 0]
     n4 = blocks[:, 1]
@@ -194,8 +224,13 @@ def nw_unpack(seg: jnp.ndarray, K: int, D: int) -> NWParams:
     nu = 2.0 * n1 + D
     mmT = m[:, :, None] * m[:, None, :]
     W_inv = -2.0 * n2 - beta[:, None, None] * mmT
-    W = jnp.linalg.inv(W_inv)
-    return NWParams(m=m, beta=beta, W=W, nu=nu)
+    W, logdet_W_inv = inv_logabsdet(W_inv)
+    return NWParams(m=m, beta=beta, W=W, nu=nu), -logdet_W_inv
+
+
+def nw_unpack(seg: jnp.ndarray, K: int, D: int) -> NWParams:
+    """Flat Normal-Wishart segment -> NWParams (inverse of `nw_pack`)."""
+    return nw_unpack_logdet(seg, K, D)[0]
 
 
 def pack_natural(q: GMMPosterior) -> jnp.ndarray:
@@ -203,11 +238,18 @@ def pack_natural(q: GMMPosterior) -> jnp.ndarray:
     return jnp.concatenate([q.alpha - 1.0, nw_pack(q)])
 
 
+def unpack_natural_logdet(phi: jnp.ndarray, K: int, D: int):
+    """Flat natural-parameter message -> (GMMPosterior, log|W| (K,)), from
+    one factorisation per component (`nw_unpack_logdet`)."""
+    alpha = phi[:K] + 1.0
+    nw, logdet_W = nw_unpack_logdet(phi[K:], K, D)
+    return (GMMPosterior(alpha=alpha, m=nw.m, beta=nw.beta, W=nw.W, nu=nw.nu),
+            logdet_W)
+
+
 def unpack_natural(phi: jnp.ndarray, K: int, D: int) -> GMMPosterior:
     """Flat natural-parameter message -> GMMPosterior (inverse of pack)."""
-    alpha = phi[:K] + 1.0
-    nw = nw_unpack(phi[K:], K, D)
-    return GMMPosterior(alpha=alpha, m=nw.m, beta=nw.beta, W=nw.W, nu=nw.nu)
+    return unpack_natural_logdet(phi, K, D)[0]
 
 
 def nw_project(seg: jnp.ndarray, K: int, D: int, *,
@@ -297,12 +339,18 @@ def dirichlet_expected_log(alpha: jnp.ndarray) -> jnp.ndarray:
     return digamma(alpha) - digamma(jnp.sum(alpha, -1, keepdims=True))
 
 
-def wishart_expected_logdet(W: jnp.ndarray, nu: jnp.ndarray) -> jnp.ndarray:
-    """E[ln |Lambda|] for Lambda ~ W(W, nu)  (Appendix A)."""
+def wishart_expected_logdet(W: jnp.ndarray, nu: jnp.ndarray,
+                            logdet_W: jnp.ndarray | None = None
+                            ) -> jnp.ndarray:
+    """E[ln |Lambda|] for Lambda ~ W(W, nu)  (Appendix A).  `logdet_W`,
+    where the caller already has log|W| (`unpack_natural_logdet`), saves
+    the factorisation of W."""
     D = W.shape[-1]
     j = jnp.arange(1, D + 1, dtype=W.dtype)
+    if logdet_W is None:
+        logdet_W = jnp.linalg.slogdet(W)[1]
     return (jnp.sum(digamma((nu[..., None] + 1.0 - j) / 2.0), -1)
-            + D * jnp.log(2.0) + jnp.linalg.slogdet(W)[1])
+            + D * jnp.log(2.0) + logdet_W)
 
 
 def nw_log_partition(q: GMMPosterior) -> jnp.ndarray:

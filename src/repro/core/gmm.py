@@ -38,15 +38,19 @@ class SuffStats(NamedTuple):
 
 
 def responsibilities(x: jnp.ndarray, q: GMMPosterior,
-                     mask: jnp.ndarray | None = None) -> jnp.ndarray:
+                     mask: jnp.ndarray | None = None,
+                     logdet_W: jnp.ndarray | None = None) -> jnp.ndarray:
     """r_jk (Bishop 10.46 / Appendix A), shape (Ni, K).
 
     ln rho_jk = E[ln pi_k] + 1/2 E[ln|L_k|] - D/2 ln 2pi
                 - 1/2 E[(x_j - mu_k)^T L_k (x_j - mu_k)]
+
+    `logdet_W` (K,): log|W| where the caller has it from unpacking
+    (`expfam.unpack_natural_logdet`); otherwise W is factored again.
     """
     D = x.shape[-1]
     e_logpi = expfam.dirichlet_expected_log(q.alpha)              # (K,)
-    e_logdet = expfam.wishart_expected_logdet(q.W, q.nu)          # (K,)
+    e_logdet = expfam.wishart_expected_logdet(q.W, q.nu, logdet_W)  # (K,)
     diff = x[:, None, :] - q.m[None, :, :]                        # (Ni, K, D)
     maha = jnp.einsum("jki,kil,jkl->jk", diff, q.W, diff)         # (Ni, K)
     e_quad = D / q.beta[None, :] + q.nu[None, :] * maha
@@ -58,7 +62,8 @@ def responsibilities(x: jnp.ndarray, q: GMMPosterior,
     return r
 
 
-def estep_terms(q: GMMPosterior, dtype=None):
+def estep_terms(q: GMMPosterior, dtype=None,
+                logdet_W: jnp.ndarray | None = None):
     """Per-component terms consumed by the fused VBE kernel
     (kernels/gmm_estep.py) — the expanded form of the Appendix-A
     log-responsibility:
@@ -70,10 +75,11 @@ def estep_terms(q: GMMPosterior, dtype=None):
 
     so that ln rho_jk = log_prior_k - (x^T Wn x - 2 x^T b + c) / 2,
     identical (up to f.p. reassociation) to `responsibilities`.
+    `logdet_W` as in `responsibilities`.
     """
     D = q.D
     e_logpi = expfam.dirichlet_expected_log(q.alpha)
-    e_logdet = expfam.wishart_expected_logdet(q.W, q.nu)
+    e_logdet = expfam.wishart_expected_logdet(q.W, q.nu, logdet_W)
     log_prior = e_logpi + 0.5 * e_logdet - 0.5 * D * jnp.log(2.0 * jnp.pi)
     Wn = q.nu[:, None, None] * q.W
     b = jnp.einsum("kde,ke->kd", Wn, q.m)
@@ -103,9 +109,9 @@ def sufficient_stats(x: jnp.ndarray, r: jnp.ndarray,
     return SuffStats(R=R, sum_x=sum_x, sum_xx=sum_xx)
 
 
-def posterior_from_stats(stats: SuffStats, prior: GMMPosterior,
-                         eps: float = 1e-12) -> GMMPosterior:
-    """Hyperparameter updates of Appendix A given (replicated) stats."""
+def _vbm_update(stats: SuffStats, prior: GMMPosterior, eps: float):
+    """Hyperparameter updates of Appendix A given (replicated) stats, with
+    the Wishart scale as its inverse: (alpha, m, beta, W^{-1}, nu)."""
     R = stats.R
     alpha = prior.alpha + R
     beta = prior.beta + R
@@ -120,23 +126,42 @@ def posterior_from_stats(stats: SuffStats, prior: GMMPosterior,
     W0_inv = jnp.linalg.inv(prior.W)
     W_inv = W0_inv + RS + cross
     W_inv = 0.5 * (W_inv + jnp.swapaxes(W_inv, -1, -2))
-    W = jnp.linalg.inv(W_inv)
-    return GMMPosterior(alpha=alpha, m=m, beta=beta, W=W, nu=nu)
+    return alpha, m, beta, W_inv, nu
+
+
+def posterior_from_stats(stats: SuffStats, prior: GMMPosterior,
+                         eps: float = 1e-12) -> GMMPosterior:
+    """Hyperparameter updates of Appendix A given (replicated) stats."""
+    alpha, m, beta, W_inv, nu = _vbm_update(stats, prior, eps)
+    return GMMPosterior(alpha=alpha, m=m, beta=beta,
+                        W=jnp.linalg.inv(W_inv), nu=nu)
+
+
+def natural_from_stats(stats: SuffStats, prior: GMMPosterior,
+                       eps: float = 1e-12) -> jnp.ndarray:
+    """`pack_natural(posterior_from_stats(stats, prior))` without the
+    W^{-1} -> W -> W^{-1} round trip: the message's n2 carries the W^{-1}
+    the update builds, so nothing per node is inverted (the local VBM
+    optimum phi*, Eq. 18)."""
+    alpha, m, beta, W_inv, nu = _vbm_update(stats, prior, eps)
+    return jnp.concatenate([alpha - 1.0,
+                            expfam.nw_pack_winv(m, beta, W_inv, nu)])
 
 
 def local_vbm_optimum(x: jnp.ndarray, q_global: GMMPosterior,
                       prior: GMMPosterior, replication: float,
-                      mask: jnp.ndarray | None = None) -> jnp.ndarray:
+                      mask: jnp.ndarray | None = None,
+                      logdet_W: jnp.ndarray | None = None) -> jnp.ndarray:
     """One VBE step + local VBM optimum -> phi*_{theta,i}  (Eqs. 17a, 18).
 
-    Returns the flat natural-parameter message of Eq. 45.
+    Returns the flat natural-parameter message of Eq. 45.  `logdet_W` as
+    in `responsibilities`.
     """
     with jax.named_scope("vb/vbe"):
-        r = responsibilities(x, q_global, mask)
+        r = responsibilities(x, q_global, mask, logdet_W)
         stats = sufficient_stats(x, r, replication)
     with jax.named_scope("vb/vbm"):
-        q_star = posterior_from_stats(stats, prior)
-        return expfam.pack_natural(q_star)
+        return natural_from_stats(stats, prior)
 
 
 # vmapped over a leading node axis: x (Nnodes, Ni, D), phi (Nnodes, P)
@@ -146,8 +171,8 @@ def local_vbm_optimum_nodes(x: jnp.ndarray, phi: jnp.ndarray,
                             mask: jnp.ndarray | None = None) -> jnp.ndarray:
     def one(xi, phii, mi):
         with jax.named_scope("vb/terms"):
-            q = expfam.unpack_natural(phii, K, D)
-        return local_vbm_optimum(xi, q, prior, replication, mi)
+            q, logdet_W = expfam.unpack_natural_logdet(phii, K, D)
+        return local_vbm_optimum(xi, q, prior, replication, mi, logdet_W)
 
     if mask is None:
         mask = jnp.ones(x.shape[:2], x.dtype)

@@ -8,7 +8,7 @@ hypothesis = pytest.importorskip(
     "hypothesis", reason="hypothesis not available in this environment")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
-from repro.core import expfam
+from repro.core import expfam, gmm
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -108,3 +108,54 @@ def test_flat_dim():
     for K, D in [(3, 2), (2, 5), (10, 52)]:
         q = random_posterior(np.random.default_rng(0), K, D)
         assert expfam.pack_natural(q).shape == (expfam.flat_dim(K, D),)
+
+
+def _stats_with_conditioned_winv(D, K=3, cond=1e3, seed=0):
+    """Replicated statistics whose VBM update's W^-1 = R C + (tiny prior
+    and cross terms), C a random rotation of eigenvalues spread over
+    `cond`: cond(W^-1) about `cond`, the order a real-width node reaches."""
+    rng = np.random.default_rng(seed)
+    R = rng.uniform(5e3, 2e4, K)
+    xbar = rng.normal(size=(K, D)) * 0.1
+    Q = np.linalg.qr(rng.normal(size=(K, D, D)))[0]
+    C = np.einsum("kij,j,klj->kil", Q, np.logspace(-np.log10(cond), 0, D), Q)
+    sum_xx = R[:, None, None] * (C + xbar[:, :, None] * xbar[:, None, :])
+    stats = gmm.SuffStats(R=jnp.asarray(R), sum_x=jnp.asarray(R[:, None] * xbar),
+                          sum_xx=jnp.asarray(sum_xx))
+    prior = expfam.noninformative_prior(K, D, beta0=0.1, w0_scale=1e4,
+                                        dtype=jnp.float64)
+    return stats, prior
+
+
+def _f32(tree):
+    return jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), tree)
+
+
+@pytest.mark.parametrize("D", [2, 52])
+def test_direct_pack_and_single_factorisation(D):
+    """The VBM post-stage packs the W^-1 it builds (`natural_from_stats`)
+    and unpack takes log|W| from its one factorisation: the same numbers
+    as the W^-1 -> W -> W^-1 round trip and `slogdet(W)` in f64, and a
+    closer n2 block in f32, where the round trip loses digits to cond(W^-1)."""
+    K = 3
+    stats, prior = _stats_with_conditioned_winv(D, K)
+    winv_block = expfam.block_labels(K, D) == expfam.BLOCK_NAMES.index("winv")
+
+    direct = gmm.natural_from_stats(stats, prior)
+    round_trip = expfam.pack_natural(gmm.posterior_from_stats(stats, prior))
+    scale = float(jnp.max(jnp.abs(direct)))
+    np.testing.assert_allclose(direct, round_trip, rtol=0, atol=1e-12 * scale)
+
+    q, logdet_W = expfam.unpack_natural_logdet(direct, K, D)
+    np.testing.assert_allclose(logdet_W, jnp.linalg.slogdet(q.W)[1],
+                               rtol=1e-12, atol=1e-12 * D)
+    cond = np.linalg.cond(np.asarray(q.W))           # = cond(W^-1)
+    assert np.all((cond > 3e2) & (cond < 3e3)), cond
+
+    s32, p32 = _f32(stats), _f32(prior)
+    err_direct = np.max(np.abs(np.asarray(
+        gmm.natural_from_stats(s32, p32), np.float64) - direct)[winv_block])
+    err_round = np.max(np.abs(np.asarray(
+        expfam.pack_natural(gmm.posterior_from_stats(s32, p32)),
+        np.float64) - direct)[winv_block])
+    assert err_direct < err_round, (err_direct, err_round)
