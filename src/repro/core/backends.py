@@ -19,7 +19,7 @@ phi is backend-invariant, only the arithmetic that produces phi* varies):
        packed straight into the message (gmm.natural_from_stats) — inside
        the same jit.
   Each node's W^{-1} is factored once per call, in step 1 (W and log|W|
-  from one LU); the post-stage inverts nothing per node.
+  from one Cholesky); the post-stage inverts nothing per node.
   Data may stream in a narrow dtype (`PrecisionPolicy.data_dtype=bf16`)
   while accumulation stays f32, mirroring `ring_combine`'s `compute_dtype`
   convention.

@@ -165,23 +165,24 @@ def block_labels(K: int, D: int):
     return np.asarray([0] * K + per * K, np.int32)
 
 
-def inv_logabsdet(A: jnp.ndarray):
-    """(inv(A), log|det A|) over the trailing two axes from ONE LU
-    factorisation P A = L U: the inverse by two triangular solves against
-    P, the log-determinant from U's diagonal.  The factorisation's own
-    permutation is used (the TPU's LU returns it): going through the
-    pivots, as `jax.scipy.linalg.lu_solve` does, adds a serial loop over
-    the rows.  (Cholesky, valid for the SPD W^{-1} this serves, was faster
-    on a TPU v5e but moved the D=2 serving cell's answers further from the
-    reference; PERF.md.)"""
-    lu, _, perm = jax.lax.linalg.lu(A)
-    P = (perm[..., :, None] == jnp.arange(A.shape[-1])).astype(A.dtype)
-    y = jax.lax.linalg.triangular_solve(lu, P, left_side=True, lower=True,
-                                        unit_diagonal=True)
-    inv = jax.lax.linalg.triangular_solve(lu, y, left_side=True, lower=False)
-    logabsdet = jnp.sum(
-        jnp.log(jnp.abs(jnp.diagonal(lu, axis1=-2, axis2=-1))), -1)
-    return inv, logabsdet
+def spd_inv_logdet(A: jnp.ndarray):
+    """(inv(A), log det A) over the trailing two axes of a symmetric
+    positive-definite A, from ONE Cholesky factorisation A = L L^T: the
+    inverse by two triangular solves against the identity (L Y = I, then
+    L^T A^-1 = Y), the log-determinant as 2 sum log diag(L).  A must be
+    SPD, as every W^{-1} this serves is (a convex combination of valid
+    natural parameters, or the output of `nw_project`, whose eigenvalue
+    floor is sized to survive unpack's rounding): for an A that is not,
+    both results are NaN where a pivoted LU would return a finite,
+    meaningless inverse."""
+    L = jax.lax.linalg.cholesky(A)
+    eye = jnp.broadcast_to(jnp.eye(A.shape[-1], dtype=A.dtype), A.shape)
+    y = jax.lax.linalg.triangular_solve(L, eye, left_side=True, lower=True)
+    inv = jax.lax.linalg.triangular_solve(L, y, left_side=True, lower=True,
+                                          transpose_a=True)
+    logdet = 2.0 * jnp.sum(
+        jnp.log(jnp.diagonal(L, axis1=-2, axis2=-1)), -1)
+    return inv, logdet
 
 
 def nw_pack_winv(m, beta, W_inv, nu) -> jnp.ndarray:
@@ -211,9 +212,10 @@ def nw_pack(q) -> jnp.ndarray:
 def nw_unpack_logdet(seg: jnp.ndarray, K: int, D: int):
     """Flat Normal-Wishart segment -> (NWParams, log|W| (K,)).
 
-    One factorisation of the carried W^{-1} gives both W and log|W| =
-    -log|W^{-1}|, so the E-step's E[ln|Lambda|] needs no second one
-    (`wishart_expected_logdet(..., logdet_W=)`)."""
+    One Cholesky factorisation of the carried (SPD) W^{-1} gives both W
+    and log|W| = -log|W^{-1}| (`spd_inv_logdet`), so the E-step's
+    E[ln|Lambda|] needs no second one (`wishart_expected_logdet(...,
+    logdet_W=)`)."""
     blocks = seg.reshape(K, 2 + D + D * D)
     n1 = blocks[:, 0]
     n4 = blocks[:, 1]
@@ -224,7 +226,7 @@ def nw_unpack_logdet(seg: jnp.ndarray, K: int, D: int):
     nu = 2.0 * n1 + D
     mmT = m[:, :, None] * m[:, None, :]
     W_inv = -2.0 * n2 - beta[:, None, None] * mmT
-    W, logdet_W_inv = inv_logabsdet(W_inv)
+    W, logdet_W_inv = spd_inv_logdet(W_inv)
     return NWParams(m=m, beta=beta, W=W, nu=nu), -logdet_W_inv
 
 
@@ -276,10 +278,16 @@ def nw_project(seg: jnp.ndarray, K: int, D: int, *,
     W_inv = -2.0 * n2 - beta[:, None, None] * mmT
     W_inv = 0.5 * (W_inv + jnp.swapaxes(W_inv, -1, -2))
     eigval, eigvec = jnp.linalg.eigh(W_inv)
-    # relative floor: reconstruction error of eigh scales with ||W^-1||, so
-    # an absolute 1e-8 floor would not survive the round trip at large norms
-    floor = jnp.maximum(min_eig,
-                        1e-10 * jnp.max(jnp.abs(eigval), -1, keepdims=True))
+    # The floor must survive the round trip to the W^{-1} that unpack
+    # factors by Cholesky: the eigh rebuild errs by ~eps ||W^-1||, and
+    # unpack's -2 n2 - beta m m^T cancels ~eps beta |m|^2 more.  So it is
+    # relative, and in f32 a few D eps of that scale (the 1e-10 term only
+    # binds in f64); an absolute 1e-8 would not survive at large norms.
+    lam_max = jnp.max(jnp.abs(eigval), -1, keepdims=True)
+    scale = lam_max + beta[:, None] * jnp.sum(m * m, -1, keepdims=True)
+    eps = jnp.finfo(W_inv.dtype).eps
+    floor = jnp.maximum(jnp.maximum(min_eig, 1e-10 * lam_max),
+                        4.0 * D * eps * scale)
     clip = jnp.any(eigval < floor, axis=-1) | (n4 != blocks[:, 1])
     eigval = jnp.maximum(eigval, floor)
     W_inv = jnp.einsum("kij,kj,klj->kil", eigvec, eigval, eigvec)

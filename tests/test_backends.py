@@ -90,27 +90,27 @@ def test_node_batched_kernel_matches_oracle():
     np.testing.assert_allclose(sxx, sxxr, rtol=1e-3, atol=5e-3)
 
 
-def _factorisation_shapes(jaxpr):
-    """Operand shapes of every `lu` / `cholesky` in a jaxpr, sub-jaxprs
-    (jit, scan, cond, pallas_call bodies) included."""
+def _factorisations(jaxpr):
+    """(primitive, operand shape) of every `lu` / `cholesky` in a jaxpr,
+    sub-jaxprs (jit, scan, cond, pallas_call bodies) included."""
     out = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name in ("lu", "cholesky"):
-            out.append(tuple(eqn.invars[0].aval.shape))
+            out.append((eqn.primitive.name, tuple(eqn.invars[0].aval.shape)))
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else [v]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    out += _factorisation_shapes(sub)
+                    out += _factorisations(sub)
     return out
 
 
 @pytest.mark.parametrize("backend", ["reference", "fused"])
 def test_local_optimum_factors_each_node_once(backend):
-    """One local optimum factors each node's W^-1 once: unpack's
-    factorisation gives W and log|W|, and the VBM post-stage packs the W^-1 it builds.  The
-    only other factorisation is the prior's W0^-1, one (K, D, D) LU that
-    every node shares."""
+    """One local optimum factors each node's W^-1 once, by Cholesky:
+    unpack's factorisation gives W and log|W|, and the VBM post-stage
+    packs the W^-1 it builds.  The only other factorisation is the
+    prior's W0^-1, one (K, D, D) factorisation that every node shares."""
     Kk, Dd, n = 3, 4, 2
     prior = expfam.noninformative_prior(Kk, Dd, dtype=jnp.float32)
     phi = jnp.broadcast_to(expfam.pack_natural(prior),
@@ -121,9 +121,10 @@ def test_local_optimum_factors_each_node_once(backend):
     jaxpr = jax.make_jaxpr(
         lambda x, mask, phi: be.local_vbm_optimum_nodes(
             x, mask, phi, prior, 2.0, Kk, Dd))(x, mask, phi)
-    shapes = _factorisation_shapes(jaxpr.jaxpr)
-    assert [s for s in shapes if s[0] == n] == [(n, Kk, Dd, Dd)], shapes
-    assert [s for s in shapes if s[0] != n] == [(Kk, Dd, Dd)], shapes
+    found = _factorisations(jaxpr.jaxpr)
+    assert [f for f in found if f[1][0] == n] == [
+        ("cholesky", (n, Kk, Dd, Dd))], found
+    assert [f[1] for f in found if f[1][0] != n] == [(Kk, Dd, Dd)], found
 
 
 def test_bf16_storage_f32_accum(setup):

@@ -159,3 +159,59 @@ def test_direct_pack_and_single_factorisation(D):
         expfam.pack_natural(gmm.posterior_from_stats(s32, p32)),
         np.float64) - direct)[winv_block])
     assert err_direct < err_round, (err_direct, err_round)
+
+
+@pytest.mark.parametrize("D", [2, 52])
+@pytest.mark.parametrize("cond", [5.0, 1e3])
+def test_spd_factorisation_accuracy(D, cond):
+    """`spd_inv_logdet` in f32 against f64 `inv` / `slogdet` of the same
+    f32 inputs, on random SPD batches of condition number `cond`: the
+    inverse's largest gap, as a share of the inverse's largest entry, is
+    within 2 cond eps, and the log-determinant's absolute gap within
+    2 D cond eps (the first-order bound |tr(A^-1 dA)| <= D cond |dA|/|A|)."""
+    eps = float(np.finfo(np.float32).eps)
+    rng = np.random.default_rng(D)
+    Q = np.linalg.qr(rng.normal(size=(200, D, D)))[0]
+    ev = np.logspace(-np.log10(cond), 0, D)
+    A = jnp.asarray(np.einsum("bij,j,bkj->bik", Q, ev, Q), jnp.float32)
+
+    inv, logdet = expfam.spd_inv_logdet(A)
+    assert inv.dtype == logdet.dtype == jnp.float32
+    inv, logdet = np.asarray(inv, np.float64), np.asarray(logdet, np.float64)
+    assert np.all(np.isfinite(inv)) and np.all(np.isfinite(logdet))
+
+    A64 = np.asarray(A, np.float64)
+    want = np.linalg.inv(A64)
+    rel = (np.max(np.abs(inv - want), axis=(1, 2))
+           / np.max(np.abs(want), axis=(1, 2)))
+    assert np.max(rel) < 2 * cond * eps, np.max(rel) / (cond * eps)
+    gap = np.abs(logdet - np.linalg.slogdet(A64)[1])
+    assert np.max(gap) < 2 * D * cond * eps, np.max(gap) / (D * cond * eps)
+
+
+@pytest.mark.parametrize("D", [2, 34, 52])
+def test_projection_output_factors_in_f32(D):
+    """ADMM's eigen-clip (`nw_project`) feeds unpack's Cholesky: in f32 a
+    W^{-1} pushed indefinite comes back, through the projection and the
+    unpack's -2 n2 - beta m m^T, as a matrix Cholesky can factor, so W and
+    log|W| are finite."""
+    K = 16
+    rng = np.random.default_rng(D)
+    Q = np.linalg.qr(rng.normal(size=(K, D, D)))[0]
+    ev = np.tile(np.logspace(2, -1, D), (K, 1))           # lambda_max 1e2
+    ev[:, -1] = -np.abs(rng.normal(size=K)) * 1e-2        # one negative
+    w_inv = np.einsum("kij,kj,klj->kil", Q, ev, Q)
+    m = rng.normal(size=(K, D)) * np.geomspace(0.1, 10, K)[:, None]
+    seg = expfam.nw_pack_winv(
+        *(jnp.asarray(a, jnp.float32)
+          for a in (m, np.full(K, 3.0), w_inv, np.full(K, D + 5.0))))
+
+    proj = expfam.nw_project(seg, K, D)
+    assert proj.dtype == jnp.float32
+    n2 = slice(2 + D, None)
+    moved = np.any(np.asarray(proj).reshape(K, -1)[:, n2]
+                   != np.asarray(seg).reshape(K, -1)[:, n2], -1)
+    assert moved.all(), moved                   # every component clipped
+    nw, logdet_w = expfam.nw_unpack_logdet(proj, K, D)
+    assert np.all(np.isfinite(np.asarray(nw.W)))
+    assert np.all(np.isfinite(np.asarray(logdet_w)))
