@@ -17,8 +17,7 @@ from repro.core.engine import (  # noqa: F401
     RingDiffusion, Schedule, run_vb,
 )
 from repro.core.expfam import (  # noqa: F401
-    GMMPosterior, enable_x64, noninformative_prior, pack_natural,
-    unpack_natural,
+    GMMPosterior, noninformative_prior, pack_natural, unpack_natural,
 )
 from repro.core.model import ConjugateExpModel, GMMModel, LinRegModel  # noqa: F401
 from repro.core import linreg  # noqa: F401  (2nd conjugate-exp instance)

@@ -110,7 +110,8 @@ class Backend(Protocol):
 
 @dataclasses.dataclass(frozen=True)
 class ReferenceBackend:
-    """core/gmm.py as-is: three einsum passes over the data per iteration."""
+    """core/gmm.py as-is: the responsibilities, then R, sum_x and sum_xx
+    as plain sums over the sample axis."""
 
     name: str = dataclasses.field(default="reference", init=False)
 

@@ -1359,7 +1359,7 @@ def vb_init(model, data, topology, *, schedule: Schedule = Schedule(),
                 f"{minibatch.control_variate!r}; expected None or 'svrg'")
         capacity = model.data_mask(data).shape[1]   # also validates shape
         if minibatch.batch_size > capacity:
-            # covering the whole node = the bit-exact full-batch path
+            # covering the whole node = the full-batch path
             minibatch = minibatch._replace(batch_size=int(capacity))
         stream0 = stream.init_state(n_nodes, minibatch.seed, int(capacity))
         if minibatch.control_variate == "svrg" \
@@ -1367,7 +1367,7 @@ def vb_init(model, data, topology, *, schedule: Schedule = Schedule(),
             # SVRG anchors: snapshot iterate + its full-batch optimum,
             # refreshed at epoch boundaries inside `_iteration`.  Inert
             # (structurally absent) at full batch, where the minibatch
-            # path is already bit-exact with the full-batch run.
+            # path already computes the full-batch statistics.
             stream0 = stream0._replace(
                 anchor_phi=init_phi,
                 anchor_full=model.local_optimum(data, init_phi,
@@ -1653,11 +1653,11 @@ def run_vb(model, data, topology, *, n_iters: int,
         the statistics stay unbiased, composing with `replication`).
         Deterministic per (seed, node, iteration):
         both executors and both compute backends see identical batches.
-        `batch_size >= n_per_node` reproduces the full-batch run
-        bit-for-bit.  `control_variate="svrg"` re-centres every
+        `batch_size >= n_per_node` reproduces the full-batch run to
+        rounding.  `control_variate="svrg"` re-centres every
         minibatch estimate on a full-batch anchor refreshed each epoch
         (still exactly unbiased; anchors ride the resumable stream
-        state, and the full-batch degeneracy stays bit-exact).
+        state, and the full-batch degeneracy carries none).
     diagnostics : also record per-iteration consensus error
     metric_nodes : evaluate the Eq. 46 metric on only the first
         `metric_nodes` rows (kl_nodes becomes (T, metric_nodes)) — used by

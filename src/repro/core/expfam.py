@@ -32,47 +32,6 @@ import jax.numpy as jnp
 from jax.scipy.special import digamma, gammaln, multigammaln
 
 
-def enable_x64() -> None:
-    """Faithful-layer entry points call this: the GMM VB recursions involve
-    log-determinants and digammas of counts ~1e4; float64 keeps the KL metric
-    (Eq. 46) trustworthy.  The framework layer never calls it."""
-    jax.config.update("jax_enable_x64", True)
-
-
-def ordered_sum(a: jnp.ndarray, chunk: int = 32) -> jnp.ndarray:
-    """Sum over the leading (sample) axis, BIT-invariant to appended zero
-    rows.
-
-    XLA is free to re-tile a plain reduce (or a dot_general contraction)
-    when the axis length changes, so `sum(x)` and `sum(pad(x, zeros))`
-    can differ in the last ulp — which breaks the serving layer's
-    padded-session == unpadded-solo bit-equality contract
-    (serving/admission.py bucketing).  This formulation pins the
-    association order by construction: pad to a multiple of `chunk`, sum
-    each fixed-shape (chunk, ...) block, and fold the block sums with a
-    SEQUENTIAL `lax.scan`.  Appending zero rows only appends all-zero
-    blocks, and `acc + 0.0` is exact, so the result is bit-identical for
-    any amount of trailing zero padding.
-
-    >>> import jax.numpy as jnp
-    >>> a = jnp.linspace(0.0, 1.0, 7)[:, None]
-    >>> b = jnp.concatenate([a, jnp.zeros((90, 1))])
-    >>> bool(jnp.all(ordered_sum(a) == ordered_sum(b)))
-    True
-    """
-    T = a.shape[0]
-    Tp = max(chunk, -(-T // chunk) * chunk)
-    if Tp != T:
-        a = jnp.pad(a, ((0, Tp - T),) + ((0, 0),) * (a.ndim - 1))
-    blocks = a.reshape((Tp // chunk, chunk) + a.shape[1:])
-
-    def fold(acc, blk):
-        return acc + jnp.sum(blk, axis=0), None
-
-    out, _ = jax.lax.scan(fold, jnp.zeros(a.shape[1:], a.dtype), blocks)
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Hyperparameter container for the GMM global posterior q(pi) prod_k q(mu,L)
 # ---------------------------------------------------------------------------
@@ -318,7 +277,6 @@ def project_to_domain(phi: jnp.ndarray, K: int, D: int, *,
 def in_domain(phi: jnp.ndarray, K: int, D: int) -> jnp.ndarray:
     """Boolean: does phi lie in the natural-parameter domain Omega (Eq. 8)?"""
     q = unpack_natural(phi, K, D)
-    W_inv = jnp.linalg.inv(q.W)  # round-trips the packed -2 n2 - beta mm^T
     # Use eigenvalues of the W^{-1} implied by the raw coordinates.
     blocks = phi[K:].reshape(K, 2 + D + D * D)
     n2 = blocks[:, 2 + D:].reshape(K, D, D)

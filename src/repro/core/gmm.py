@@ -91,21 +91,12 @@ def estep_terms(q: GMMPosterior, dtype=None,
 
 def sufficient_stats(x: jnp.ndarray, r: jnp.ndarray,
                      replication: float) -> SuffStats:
-    """Replicated stats (Appendix A).  `replication` is the network size N.
-
-    The data-axis reductions go through `expfam.ordered_sum` (multiply
-    then fixed-chunk sequential sum) rather than einsum contractions:
-    XLA re-tiles a dot_general (and even a plain reduce) when the axis
-    length changes, so mask-zero padding slots appended by the serving
-    layer's bucketed admission (serving/admission.py) would perturb the
-    last ulp.  `ordered_sum` pins the association order, keeping padded
-    statistics BIT-equal to the unpadded computation.
-    """
-    R = replication * expfam.ordered_sum(r)                       # (K,)
+    """Replicated stats (Appendix A).  `replication` is the network size N."""
+    R = replication * jnp.sum(r, axis=0)                          # (K,)
     rx = r[:, :, None] * x[:, None, :]                            # (j, K, D)
-    sum_x = replication * expfam.ordered_sum(rx)                  # (K, D)
-    sum_xx = replication * expfam.ordered_sum(
-        rx[:, :, :, None] * x[:, None, None, :])                  # (K, D, D)
+    sum_x = replication * jnp.sum(rx, axis=0)                     # (K, D)
+    sum_xx = replication * jnp.sum(
+        rx[:, :, :, None] * x[:, None, None, :], axis=0)          # (K, D, D)
     return SuffStats(R=R, sum_x=sum_x, sum_xx=sum_xx)
 
 

@@ -28,7 +28,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import expfam
 from jax.scipy.special import digamma, gammaln
 
 from repro.core import engine
@@ -125,15 +124,11 @@ def kl(q: NGPosterior, p: NGPosterior) -> jnp.ndarray:
 def local_optimum(X, y, mask, q0: NGPosterior, replication: float):
     """phi*_i for node data (X (Ni,D), y (Ni,)) replicated `N` times."""
     w = mask
-    # data-axis sums via expfam.ordered_sum (not einsum) so mask-zero
-    # padding slots appended by the serving layer's bucketed admission
-    # contribute exact +0.0 — the statistics stay BIT-equal to the
-    # unpadded computation (see gmm.sufficient_stats).
     Xw = X * w[:, None]                                 # (n, D)
-    XtX = expfam.ordered_sum(Xw[:, :, None] * X[:, None, :]) * replication
-    Xty = expfam.ordered_sum(Xw * y[:, None]) * replication
-    yty = expfam.ordered_sum((y * y * w)[:, None])[0] * replication
-    n = expfam.ordered_sum(w[:, None])[0] * replication
+    XtX = jnp.sum(Xw[:, :, None] * X[:, None, :], axis=0) * replication
+    Xty = jnp.sum(Xw * y[:, None], axis=0) * replication
+    yty = jnp.sum(y * y * w) * replication
+    n = jnp.sum(w) * replication
     V = q0.V + XtX
     m = jnp.linalg.solve(V, q0.V @ q0.m + Xty)
     a = q0.a + n / 2.0

@@ -135,9 +135,10 @@ class ConjugateExpModel(Protocol):
         mask-zero padding (values zero, mask zero).  The serving layer's
         bucketed admission (serving/admission.py) pads sessions up to a
         shared ladder rung so near-same-shape sessions share one compiled
-        fleet; the appended slots are inert — the engine's ordered
-        reductions keep the padded trajectory BIT-equal to the unpadded
-        one — and double as free capacity for `append_node_data`.
+        fleet; the appended slots add nothing to any statistic, so the
+        padded trajectory agrees with the unpadded one to rounding
+        (docs/bucketed-admission.md), and they double as free capacity
+        for `append_node_data`.
         Raises ValueError if `capacity` is below the current buffer size.
         Host-side (eager)."""
         ...

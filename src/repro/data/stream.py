@@ -50,11 +50,13 @@ Appendix-A `replication` factor untouched.  Since the GMM natural
 parameters are linear in the sufficient statistics, E[phi*_minibatch] =
 phi*_full exactly (tests/test_streaming.py asserts this by Monte Carlo).
 
-Full-batch degeneracy is bit-exact by construction: with `batch_size` =
-the per-node sample capacity there is one window per epoch, the sorted
-window is the identity gather, and the T/T scale multiplies the mask by
-exactly 1.0 — so `MinibatchSpec(batch_size=n_per_node)` reproduces the
-full-batch run bit-for-bit on every estimator and executor.
+Full-batch degeneracy: with `batch_size` = the per-node sample capacity
+there is one window per epoch, the sorted window is the identity gather,
+and the T/T scale multiplies the mask by exactly 1.0 — so
+`MinibatchSpec(batch_size=n_per_node)` computes the full-batch
+statistics on every estimator and executor.  It is a different program
+(the gather is in it), so it agrees with the full-batch run to rounding,
+not bit for bit.
 
 Variance reduction (`MinibatchSpec(control_variate="svrg")`): on top of
 reshuffling, `StreamState` can carry a full-batch ANCHOR — a snapshot
@@ -206,7 +208,7 @@ def _select_one(key: jnp.ndarray, base_mask: jnp.ndarray, t: jnp.ndarray,
     end when batch_size does not divide the capacity, so every slot is
     visited at least once per epoch (exactly once when it divides) —
     sorted ascending (with one chunk per epoch this makes the gather the
-    identity permutation: the bit-exact full-batch degeneracy).
+    identity permutation: the full-batch degeneracy).
 
     The weight on every selected VALID point is the constant T/B
     (capacity/batch): any fixed index window of a uniform permutation is a

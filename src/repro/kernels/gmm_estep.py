@@ -142,14 +142,6 @@ def gmm_estep_nodes(x, mask, log_prior, Wn, b, c, *, interpret: bool,
     once."""
     N, T, D = x.shape
     K = log_prior.shape[-1]
-    # The block size is a function of `block_t` ONLY — never of T.  Every
-    # input is padded up to a multiple of the same block shape, so a
-    # mask-zero-padded copy of the data sees bit-identical blocks (the
-    # shared prefix) plus all-zero blocks whose statistics accumulate an
-    # exact +0.0 through the sequential data-block grid.  That makes the
-    # emitted statistics BIT-invariant to trailing padding — the serving
-    # layer's bucketed-admission contract (serving/admission.py), mirroring
-    # expfam.ordered_sum on the reference path.
     bt = max(8, block_t)
     Tp = ((T + bt - 1) // bt) * bt
     if Tp != T:

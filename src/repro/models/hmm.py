@@ -29,7 +29,7 @@ axis (whole chains are the iid unit), so the protocol-level streaming /
 padding / append plumbing applies unchanged: minibatches subsample chains
 with unbiased T/B rescaling (per-chain statistics are linear in the scaled
 mask), and bucketed-admission padding appends mask-zero chains whose
-statistics are exact +0.0 through `expfam.ordered_sum` — bit-invisible.
+statistics are zero.
 """
 from __future__ import annotations
 
@@ -166,14 +166,12 @@ class HMMModel(blocks.BlockModel):
 
         gamma, xi = jax.vmap(per_chain)(x)      # (S, L, K), (S, L-1, K, K)
 
-        # Expected counts, replicated (Appendix-A style).  The chain axis
-        # is the sample axis: reductions go through expfam.ordered_sum so
-        # mask-zero padding chains contribute exact +0.0 (bit-invisible
-        # under bucketed admission); within-chain sums are fixed-length.
-        pi_counts = replication * expfam.ordered_sum(
-            w[:, None] * gamma[:, 0, :])                            # (K,)
-        trans_counts = replication * expfam.ordered_sum(
-            w[:, None, None] * jnp.sum(xi, axis=1))                 # (K, K)
+        # Expected counts, replicated (Appendix-A style); the chain axis
+        # is the sample axis.
+        pi_counts = replication * jnp.sum(
+            w[:, None] * gamma[:, 0, :], axis=0)                    # (K,)
+        trans_counts = replication * jnp.sum(
+            w[:, None, None] * jnp.sum(xi, axis=1), axis=0)         # (K, K)
 
         # Emission block: gamma-weighted chains, flattened to one sample
         # axis (row-major keeps padded chains at the tail), reuse the GMM

@@ -29,8 +29,7 @@ Szx_d = sum_j w_j mu_j x_jd, Sxx_d = sum_j w_j x_jd^2, n = sum_j w_j —
 Eqs. 17a/18 once more.  The flat natural parameters are LINEAR in these
 statistics (the linreg algebra), and the statistics are linear in the
 mask, so streaming minibatches and the SVRG control variate stay exactly
-unbiased, and `expfam.ordered_sum` reductions keep bucketed-admission
-padding bit-invisible.
+unbiased, and bucketed-admission padding (mask zero) adds nothing.
 
 Data convention: the protocol default `(x (N, T, D), mask (N, T))`.
 """
@@ -40,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import blocks, expfam, linreg
+from repro.core import blocks, linreg
 from repro.core.linreg import NGPosterior
 
 
@@ -97,17 +96,16 @@ class PPCAModel(blocks.BlockModel):
         q = self.unpack(phi)
         sigma, mu = latent_posterior(x, q)
 
-        # replicated latent statistics; sample-axis reductions through
-        # expfam.ordered_sum (padding bit-invisibility, cf. linreg)
+        # replicated latent statistics
         p0 = self.prior
         wx = x * w[:, None]                                        # (T, D)
         muw = mu * w[:, None]                                      # (T, Q)
-        n = expfam.ordered_sum(w[:, None])[0] * replication
-        Szz = (expfam.ordered_sum(muw[:, :, None] * mu[:, None, :])
+        n = jnp.sum(w) * replication
+        Szz = (jnp.sum(muw[:, :, None] * mu[:, None, :], axis=0)
                * replication + n * sigma)                          # (Q, Q)
-        Szx = expfam.ordered_sum(
-            wx[:, :, None] * mu[:, None, :]) * replication         # (D, Q)
-        Sxx = expfam.ordered_sum(wx * x) * replication             # (D,)
+        Szx = jnp.sum(
+            wx[:, :, None] * mu[:, None, :], axis=0) * replication  # (D, Q)
+        Sxx = jnp.sum(wx * x, axis=0) * replication                # (D,)
 
         def row(V0, m0, a0, b0, szx, sxx):
             V = V0 + Szz

@@ -13,12 +13,12 @@ fixed-shape device batches:
   (`bucket_capacity` / `bucket_for`) lets near-same-shape sessions share
   one too — each session's per-node data capacity is padded up to the
   next ladder rung with mask-zero slots (`model.pad_to_capacity`), which
-  the engine's ordered reductions keep bit-equal to the unpadded run
-  (docs/bucketed-admission.md).  Everything here keys on the model's
-  protocol surface only (`data_mask` / `pad_to_capacity`, both
-  block-layer defaults since PR 9), so the whole model zoo — GMM, LinReg,
-  HMM, PPCA (docs/model-zoo.md) — buckets identically with zero
-  per-model code.
+  add nothing to any statistic, so the padded run agrees with the
+  unpadded one to rounding (docs/bucketed-admission.md).  Everything
+  here keys on the model's protocol surface only (`data_mask` /
+  `pad_to_capacity`, both block-layer defaults since PR 9), so the whole
+  model zoo — GMM, LinReg, HMM, PPCA (docs/model-zoo.md) — buckets
+  identically with zero per-model code.
 
 One home for those rules so the two engines cannot drift apart, plus
 `data_axis_mesh` — the "1-D data mesh over whatever devices exist" both

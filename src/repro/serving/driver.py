@@ -31,13 +31,13 @@ continuous-batching decomposition used by LM inference engines:
   ladder rung (`admission.bucket_capacity`) and per-iteration hyper
   constants (tau/d0, rho/xi) lift to per-slot fleet arrays
   (`engine.session_hyper`), so mixed-shape mixed-hyper sessions share
-  one compiled fleet — bit-equal to their solo runs via the engine's
-  ordered reductions (docs/bucketed-admission.md).
+  one compiled fleet, each agreeing with its solo run to rounding
+  (docs/bucketed-admission.md).
 * **Eviction is safe** because of the absolute-`t` resumability contract
   (engine.VBState): every per-iteration source — minibatch epochs, link
   drops, eta/kappa ramps — is a pure function of the session's own `t`,
   so a session's trajectory is independent of WHEN its slices run and a
-  finished-then-extended session re-enters any free slot bit-exactly.
+  finished-then-extended session can re-enter any free slot.
 
 `VBDriver` is the scheduler; `serving/vb_service.py` keeps its public
 API as a thin wrapper, and `serving/engine.py`'s LM `Engine` reuses
@@ -257,8 +257,8 @@ def _gated_step(step_fn, axis=None):
     """Wrap the engine's one-iteration kernel with per-session budget /
     early-stop gating: inactive sessions (converged, or budget spent)
     keep their state bit-for-bit and their absolute t frozen, so a
-    session that early-stops inside a fleet ends in exactly the state a
-    solo `vb_run` of the same length would have produced.  A FREE slot
+    session that early-stops inside a fleet runs exactly the iterations
+    a solo `vb_run` of the same length would have run.  A FREE slot
     is simply a session with `conv=True, budget=0` — the same gate is
     the driver's active mask.  Under the mesh executor (`axis`) the
     early-stop delta is pmean-reduced so every shard takes the identical
@@ -610,9 +610,9 @@ class VBDriver:
         ladder rung (`admission.bucket_capacity`) with mask-zero slots,
         so near-same-shape sessions share one compiled fleet; a float
         (> 1) is a custom ladder growth factor (e.g. 1.25); None keeps
-        the PR-6 exact-signature grouping.  Bit-safe: the engine's
-        ordered reductions make padded trajectories bit-equal to
-        unpadded ones (docs/bucketed-admission.md).  Minibatch sessions
+        the PR-6 exact-signature grouping.  Padding slots add nothing
+        to any statistic, so a padded session agrees with its unpadded
+        run to rounding (docs/bucketed-admission.md).  Minibatch sessions
         are never padded (the streaming sampler's epoch permutations are
         a function of the true capacity), nor are data pytrees the model
         cannot pad (no `pad_to_capacity`, e.g. a LinReg phi* stack).
@@ -996,7 +996,7 @@ class VBDriver:
         (into padding slots — `model.append_node_data`) and un-latch the
         session's convergence flag.  An EVICTED session whose budget
         still has room goes back through the arrival queue and resumes
-        in any free slot (bit-exact, absolute-t contract).
+        in any free slot (absolute-t contract).
 
         A BUCKETED session whose buffer overflows is not an error: the
         session is evicted from its fleet, its buffers regrown to the
@@ -1149,7 +1149,7 @@ class VBDriver:
     def _maybe_requeue(self, rid: str) -> None:
         """Re-queue an evicted session that has work again (new data or
         extended budget); absolute-t resumability makes re-admission
-        into any free slot bit-safe."""
+        into any free slot safe."""
         fin = self._finished[rid]
         rec = fin["record"]
         if bool(np.asarray(rec["conv"])) \

@@ -9,8 +9,8 @@ the continuous-batching scheduler in `serving/driver.py`:
 * **admits** requests into fleet groups keyed by the BUCKETED data
   shape signature plus the static run configuration: per-node data
   buffers are padded with mask-zero slots up to a shared capacity-ladder
-  rung (`admission.bucket_capacity`, bit-equal by the engine's ordered
-  reductions) and per-iteration hyperparameters like the schedule's tau
+  rung (`admission.bucket_capacity`; the result agrees with the solo run
+  to rounding) and per-iteration hyperparameters like the schedule's tau
   or ADMM's rho are lifted to per-slot fleet arrays
   (`engine.hyper_names`) — so mixed-shape, mixed-hyper tenants run as
   ONE device batch (docs/bucketed-admission.md);

@@ -7,9 +7,10 @@ import pytest
 
 
 CODE_FAITHFUL = r"""
+from repro import runtime
+runtime.use_platform_precision()
 import jax
 from repro.core import expfam
-expfam.enable_x64()
 import jax.numpy as jnp
 from repro.core import algorithms, distributed, network
 from repro.data import synthetic

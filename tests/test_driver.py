@@ -7,12 +7,11 @@ The two acceptance properties:
   exactly once per group configuration (`DriverStats.compiles`);
 * **driver scheduling is invisible to the numerics** — a session's
   trajectory is a pure function of its own absolute `t` (the engine's
-  resumability contract), so driver-scheduled sessions are bit-equal to
-  a solo `vb_run` of the same length for elementwise-combine topologies
-  (Ring/Fusion/Isolated), and bit-INVARIANT to the arrival/eviction
-  pattern for every topology (matmul combines differ from the solo
-  single-session GEMM shape by ~1 ulp — see docs/continuous-batching.md
-  — so Diffusion/ADMM get a 1e-9 closeness check instead).
+  resumability contract), so driver-scheduled sessions have the same
+  posterior as a solo `vb_run` of the same length to rounding (the fleet
+  is a different program — see docs/continuous-batching.md), and are
+  bit-INVARIANT to the arrival/eviction pattern for every topology (the
+  same fleet program either way).
 
 Plus the scheduler mechanics: arrival staggering, fleet-full queueing,
 the background thread, background checkpoint writes, eviction lifecycle
@@ -21,10 +20,10 @@ edges, and the LM engine sharing the same primitives.
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import assert_same_posterior
 from repro.checkpoint import ckpt
 from repro.core import engine, expfam, network
 from repro.core import model as model_lib
@@ -82,12 +81,12 @@ def test_slot_table_reuse_lowest_first():
 
 
 # ---------------------------------------------------------------------------
-# Acceptance: join/leave without recompilation, bit-equal to solo
+# Acceptance: join/leave without recompilation, same posterior as solo
 # ---------------------------------------------------------------------------
 def test_join_leave_no_recompile_and_bit_equal_solo(setup):
     """5 ring sessions with mixed budgets flow through a 3-slot fleet:
-    the slice fn traces ONCE, and every session's final phi is
-    bit-identical to a solo vb_run of its own length."""
+    the slice fn traces ONCE, and every session's final phi matches a
+    solo vb_run of its own length to rounding."""
     mdl, adj, W, datasets = setup
     topo = engine.RingDiffusion()
     budgets = [16, 24, 40, 16, 24]
@@ -107,8 +106,7 @@ def test_join_leave_no_recompile_and_bit_equal_solo(setup):
         s = out[rid]
         assert s.done and s.evicted and s.t == n
         solo = engine.run_vb(mdl, (d.x, d.mask), topo, n_iters=n)
-        np.testing.assert_array_equal(np.asarray(solo.phi),
-                                      np.asarray(s.phi), err_msg=rid)
+        assert_same_posterior(s.phi, solo.phi, err_msg=rid)
 
 
 def test_one_trace_per_group_config(setup):
@@ -131,12 +129,8 @@ def test_scheduling_invariance_matmul_topologies(setup):
     """Diffusion/ADMM (matmul combines): the scheduling QUANTUM is
     bit-invisible — the same admission into the same slots driven with
     different slice lengths (different eviction boundaries, with slots
-    going idle at different ticks) gives bit-identical phi — and the
-    result stays 1e-9-close to solo.  (Literal bit-equality to solo is a
-    slot-position property of the batched GEMM: remainder-column
-    micro-kernels differ by global column index, a ~1-ulp/step effect —
-    see docs/continuous-batching.md.  Elementwise-combine topologies ARE
-    bit-equal to solo: test_join_leave_no_recompile_and_bit_equal_solo.)"""
+    going idle at different ticks) runs the same fleet program and gives
+    bit-identical phi — and the result matches solo to rounding."""
     mdl, adj, W, datasets = setup
     budgets = [12, 18, 24]
     for topo_fn in (lambda: engine.Diffusion(W),
@@ -155,7 +149,7 @@ def test_scheduling_invariance_matmul_topologies(setup):
             np.testing.assert_array_equal(a, b)
         for d, n, a in zip(datasets, budgets, runs[0]):
             solo = engine.run_vb(mdl, (d.x, d.mask), topo_fn(), n_iters=n)
-            assert float(jnp.max(jnp.abs(solo.phi - a))) < 1e-9
+            assert_same_posterior(a, solo.phi)
 
 
 # ---------------------------------------------------------------------------

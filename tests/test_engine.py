@@ -242,9 +242,10 @@ def test_linreg_model_from_raw_data(linreg_setup):
 # Sharded executor == single-array executor (same step function)
 # ---------------------------------------------------------------------------
 CODE_EXECUTOR_EQUIV = r"""
+from repro import runtime
+runtime.use_platform_precision()
 import jax
 from repro.core import expfam
-expfam.enable_x64()
 import jax.numpy as jnp
 from repro.core import engine, network
 from repro.core import model as model_lib

@@ -316,9 +316,10 @@ def test_service_rejects_bad_requests(setup):
 # Mesh executor: session resume + fleet service under shard_map
 # ---------------------------------------------------------------------------
 CODE_MESH_SESSION = r"""
+from repro import runtime
+runtime.use_platform_precision()
 import jax
 from repro.core import expfam
-expfam.enable_x64()
 import jax.numpy as jnp
 from repro.core import engine, network
 from repro.core import model as model_lib

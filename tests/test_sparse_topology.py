@@ -215,9 +215,10 @@ def test_split_resume_bitexact_new_topologies(setup):
 # Mesh executor: sparse combines under shard_map == single-array
 # ---------------------------------------------------------------------------
 CODE_MESH_SPARSE = r"""
+from repro import runtime
+runtime.use_platform_precision()
 import jax
 from repro.core import expfam
-expfam.enable_x64()
 import numpy as np, jax.numpy as jnp
 from repro.core import engine, network
 from repro.core import model as model_lib

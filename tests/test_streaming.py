@@ -3,10 +3,10 @@
 Pins the two contracts of the streaming subsystem (data/stream.py +
 engine.run_vb(minibatch=) + the topologies' link schedules):
 
-1. *Full-batch degeneracy is bit-exact*: `MinibatchSpec(batch_size =
-   n_per_node)` reproduces the full-batch run bit-for-bit on all five
-   estimators and both executors — streaming off the golden path costs
-   nothing, not even a ulp.
+1. *Full-batch degeneracy*: `MinibatchSpec(batch_size = n_per_node)`
+   reproduces the full-batch run to rounding
+   (`conftest.assert_same_posterior`) on all five estimators and both
+   executors.
 2. *Minibatch natural gradients are unbiased*: the GMM natural parameters
    are linear in the sufficient statistics, and the scaled-mask rescaling
    (n_i/|B_i|) makes the minibatch statistics unbiased, so the
@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import assert_same_posterior
 from repro.core import algorithms, engine, expfam, linreg, network
 from repro.core import model as model_lib
 from repro.data import stream, synthetic
@@ -63,7 +64,7 @@ def _estimators(adj, W):
 
 
 # ---------------------------------------------------------------------------
-# 1. bit-exact full-batch degeneracy
+# 1. full-batch degeneracy
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("est", ["cvb", "noncoop", "nsg_dvb", "dsvb",
                                  "dvb_admm"])
@@ -76,9 +77,8 @@ def test_full_batch_spec_is_bit_identical(setup, est):
                       init_phi=phi0,
                       minibatch=stream.MinibatchSpec(batch_size=N_PER),
                       **kw)
-    np.testing.assert_array_equal(np.asarray(a.phi), np.asarray(b.phi))
-    np.testing.assert_array_equal(np.asarray(a.kl_nodes),
-                                  np.asarray(b.kl_nodes))
+    assert_same_posterior(b.phi, a.phi)
+    assert_same_posterior(b.kl_nodes, a.kl_nodes)
 
 
 def test_full_batch_degeneracy_is_identity_gather(setup):
@@ -220,7 +220,7 @@ def test_linreg_streaming_full_batch_parity():
     b = engine.run_vb(lr, (X, y, mask), engine.FusionCenter(), n_iters=5,
                       schedule=engine.ONE_SHOT,
                       minibatch=stream.MinibatchSpec(batch_size=ni))
-    np.testing.assert_array_equal(np.asarray(a.phi), np.asarray(b.phi))
+    assert_same_posterior(b.phi, a.phi)
     c = engine.run_vb(lr, (X, y, mask), engine.FusionCenter(), n_iters=5,
                       schedule=engine.ONE_SHOT,
                       minibatch=stream.MinibatchSpec(batch_size=5))
@@ -327,12 +327,12 @@ def test_link_schedule_validation():
 # 4. executor equivalence with streaming + failing links on
 # ---------------------------------------------------------------------------
 CODE_STREAMING_EXEC = r"""
+from repro import runtime
+runtime.use_platform_precision()
 import jax
-from repro.core import expfam
-expfam.enable_x64()
 import jax.numpy as jnp
 import numpy as np
-from repro.core import engine, network
+from repro.core import engine, expfam, network
 from repro.core import model as model_lib
 from repro.data import stream, synthetic
 
@@ -346,13 +346,16 @@ mexec = engine.MeshExecutor(mesh, "data")
 mdl = model_lib.GMMModel(prior, K, D)
 mb = stream.MinibatchSpec(batch_size=8, seed=3)
 
-# bit-exact full-batch degeneracy holds under the mesh executor too
+# the full-batch degeneracy holds under the mesh executor too
+# (conftest.assert_same_posterior, inlined)
 full = engine.run_vb(mdl, (data.x, data.mask), engine.Diffusion(W),
                      n_iters=15, executor=mexec)
 fb = engine.run_vb(mdl, (data.x, data.mask), engine.Diffusion(W),
                    n_iters=15, executor=mexec,
                    minibatch=stream.MinibatchSpec(batch_size=24))
-assert bool(jnp.all(full.phi == fb.phi)), "mesh full-batch spec not bit-exact"
+gap = float(jnp.max(jnp.abs(fb.phi - full.phi)))
+assert gap <= 1e-12 * float(jnp.max(jnp.abs(full.phi))), \
+    f"mesh full-batch spec gap {gap}"
 
 # ADMM runs with the Eq. 38b projection use SHORT horizons: the eigen-clip
 # is a discontinuous branch, and once the noisy streaming trajectory grazes
